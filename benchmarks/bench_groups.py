@@ -35,7 +35,6 @@ _CHILD = r"""
 import json
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T, bus
 from repro.core.gossip import GossipSpec, mix_pytree_reference
 from repro.launch.hlo_cost import analyze_hlo
@@ -61,8 +60,8 @@ for d in DEGREES:
     topo = topo_of(d)
     ref = mix_pytree_reference(params, topo.A)
     for k in KS:
-        mesh = compat.make_mesh((M, k), ("data", "model"),
-                                axis_types=(compat.AxisType.Auto,) * 2,
+        mesh = jax.make_mesh((M, k), ("data", "model"),
+                                axis_types=(jax.sharding.AxisType.Auto,) * 2,
                                 devices=jax.devices()[: M * k])
         spec = GossipSpec(topology=topo, backend="fused",
                           worker_axes=("data",),
@@ -71,7 +70,7 @@ for d in DEGREES:
         pspecs = {"w": P("data", None, m_ax, None),
                   "emb": P("data", None, m_ax),
                   "v": P("data", None, None)}
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p = jax.tree.map(lambda x, s: jax.device_put(
                 x, jax.NamedSharding(mesh, s)), params, pspecs)
             f = jax.jit(lambda q: bus.mix_bus(q, spec, mesh,
@@ -97,6 +96,9 @@ def run(quick: bool = False) -> list[dict]:
     ks = [1, 2] if quick else [1, 2, 4]
     degrees = [1, 2] if quick else [1, 2, 3]
     env = dict(os.environ)
+    # the sweep counts HLO on forced host devices by design; pinned to the
+    # CPU, the child never asks for a chip this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={M * max(ks)}")
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),

@@ -49,6 +49,9 @@ def main() -> None:
     import importlib
 
     from benchmarks import common
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
 
     argv = [a for a in sys.argv[1:]]
     quick = "--quick" in argv

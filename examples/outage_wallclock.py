@@ -154,4 +154,7 @@ def main(quick: bool = False, trace: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     main(quick="--quick" in sys.argv[1:], trace="--trace" in sys.argv[1:])

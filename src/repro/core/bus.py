@@ -45,10 +45,10 @@ The bus instead:
    one (double-buffered software pipeline; ``nchunks=1`` keeps the
    one-collective-per-permutation guarantee).
 
-Without a mesh the bus runs a single-process emulation: the permutation is a
-row gather on the leading worker dim, numerically identical to the
-distributed path (same kernel, same summation order) — this is what the
-fp32-exactness tests pin down.
+Without a mesh the bus runs a single-process emulation: the kernel reads each
+permutation's neighbor rows in place from the other workers' rows of the
+buffer, numerically identical to the distributed path (same kernel, same
+summation order) — this is what the fp32-exactness tests pin down.
 """
 from __future__ import annotations
 
@@ -59,9 +59,14 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+# The row-split re-assembly is the same on every model shard, so its gather
+# must type as Invariant over the model axis for jax.shard_map's vma check;
+# jax.lax.all_gather types its result Varying, and JAX 0.9 exports no
+# public invariant gather.
+from jax._src.lax.parallel import all_gather_invariant
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, telemetry
+from repro import telemetry
 from repro.kernels.gossip_mix.kernel import (
     DEFAULT_BLOCK_C,
     DEFAULT_BLOCK_R,
@@ -341,6 +346,47 @@ def plan_layout(tree: PyTree, *, lead_ndim: int = 1,
     return layout
 
 
+def _tile_cut(shape: tuple[int, ...], dtype) -> int:
+    """Rows of a matrix leaf that fill whole sublane tiles, else 0.
+
+    A matrix whose row count is not a whole number of sublane tiles (the
+    49155-row granite vocab table) sits in tiled HBM with a ragged last
+    tile. Reshaping it to or from a flat row in one piece took the TPU
+    compiler about a minute for that one leaf, so the bus moves its whole
+    tiles and its ragged tail as two pieces.
+    """
+    sub = sublane_rows(dtype)
+    if len(shape) != 2 or shape[0] < sub or shape[0] % sub == 0:
+        return 0
+    return shape[0] - shape[0] % sub
+
+
+def _flatten(x: jax.Array, lead_ndim: int) -> jax.Array:
+    """``x.reshape(lead + (-1,))``, ragged matrices in two pieces."""
+    lead = x.shape[:lead_ndim]
+    cut = _tile_cut(x.shape[lead_ndim:], x.dtype)
+    if not cut:
+        return jnp.reshape(x, lead + (-1,))
+    head = jax.lax.slice_in_dim(x, 0, cut, axis=lead_ndim)
+    tail = jax.lax.slice_in_dim(x, cut, x.shape[lead_ndim], axis=lead_ndim)
+    return jnp.concatenate([head.reshape(lead + (-1,)),
+                            tail.reshape(lead + (-1,))], axis=-1)
+
+
+def _unflatten(flat: jax.Array, shape: tuple[int, ...]) -> jax.Array:
+    """Inverse of :func:`_flatten` for trailing ``shape``."""
+    lead = flat.shape[:-1]
+    cut = _tile_cut(shape, flat.dtype)
+    if not cut:
+        return flat.reshape(lead + shape)
+    n = cut * shape[1]
+    head = jax.lax.slice_in_dim(flat, 0, n, axis=-1)
+    tail = jax.lax.slice_in_dim(flat, n, flat.shape[-1], axis=-1)
+    return jnp.concatenate(
+        [head.reshape(lead + (cut, shape[1])),
+         tail.reshape(lead + (shape[0] - cut, shape[1]))], axis=len(lead))
+
+
 def pack(tree: PyTree, layout: BusLayout, *, lead_ndim: int = 1,
          shard_index: Any = 0) -> list[jax.Array]:
     """Flatten ``tree`` into one (lead..., R, C) buffer per dtype group.
@@ -354,9 +400,7 @@ def pack(tree: PyTree, layout: BusLayout, *, lead_ndim: int = 1,
     for g in layout.groups:
         parts = []
         for slot in g.slots:
-            x = leaves[slot.leaf_id]
-            lead = x.shape[:lead_ndim]
-            flat = jnp.reshape(x, lead + (-1,))
+            flat = _flatten(leaves[slot.leaf_id], lead_ndim)
             if not slot.sharded and layout.shards > 1:
                 pad = layout.shards * slot.chunk - slot.size
                 if pad:
@@ -374,7 +418,11 @@ def pack(tree: PyTree, layout: BusLayout, *, lead_ndim: int = 1,
             width = [(0, 0)] * lead_ndim + [(0, pad)]
             flat = jnp.pad(flat, width)
         bufs.append(flat.reshape(flat.shape[:lead_ndim] + (g.rows, g.cols)))
-    return bufs
+    # Fence the packed buffers (and, in unpack, the mixed ones) off from
+    # their producers and consumers: fused with the leaf reshapes, a
+    # granite-width bus took the TPU compiler over a minute and 12 GB of
+    # host memory without finishing; fenced, it compiles in 10 s.
+    return jax.lax.optimization_barrier(bufs)
 
 
 def unpack(bufs: Sequence[jax.Array], layout: BusLayout, *,
@@ -389,6 +437,7 @@ def unpack(bufs: Sequence[jax.Array], layout: BusLayout, *,
     ICI, never the inter-worker gossip links).
     """
     leaves: list[jax.Array | None] = [None] * len(layout.shapes)
+    bufs = jax.lax.optimization_barrier(list(bufs))   # see pack
     for g, buf in zip(layout.groups, bufs):
         lead = buf.shape[:lead_ndim]
         flat = buf.reshape(lead + (-1,))
@@ -402,20 +451,29 @@ def unpack(bufs: Sequence[jax.Array], layout: BusLayout, *,
             if slot.sharded or layout.shards == 1:
                 piece = jax.lax.slice_in_dim(
                     flat, slot.offset, slot.offset + slot.chunk, axis=lead_ndim)
-                leaves[slot.leaf_id] = piece.reshape(
-                    lead + layout.shapes[slot.leaf_id])
+                leaves[slot.leaf_id] = _unflatten(
+                    piece, layout.shapes[slot.leaf_id])
             else:
                 off = slot.offset - g.split_off
                 piece = jax.lax.slice_in_dim(
                     gathered, off, off + slot.chunk, axis=1)
                 piece = piece.reshape(-1)[:slot.size]
-                leaves[slot.leaf_id] = piece.reshape(layout.shapes[slot.leaf_id])
+                leaves[slot.leaf_id] = _unflatten(
+                    piece, layout.shapes[slot.leaf_id])
     return layout.treedef.unflatten(leaves)
 
 
 # ---------------------------------------------------------------------------
 # Bulk consensus over packed buffers
 # ---------------------------------------------------------------------------
+
+
+def _ambient_mesh(mesh):
+    """``mesh``, else the mesh set by ``jax.set_mesh``; None when neither
+    exists (the bus then runs its single-process emulation)."""
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _split_perms(spec) -> tuple[float, list[tuple[float, np.ndarray]]]:
@@ -478,7 +536,7 @@ def _mix_group_chunked(x2, u2, rows, block_r, block_c, weights, eta, pairs,
     def permute(c):
         start, size = chunks[c]
         x_c = jax.lax.slice_in_dim(x2, start, start + size, axis=0)
-        return jnp.stack([jax.lax.ppermute(x_c, axes, pr) for pr in pairs])
+        return [jax.lax.ppermute(x_c, axes, pr) for pr in pairs]
 
     def flat_prefix(pieces):
         head = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, 0)
@@ -539,10 +597,10 @@ def _mix_buffers_sharded(bufs, upd_bufs, spec, mesh, weights, eta, perms,
             outs.append(out[None])
         return tuple(outs)
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         f, mesh=mesh, in_specs=in_specs,
         out_specs=tuple(P(spec.worker_axes) for _ in bufs),
-        axis_names=set(spec.worker_axes),
+        axis_names=set(spec.worker_axes), check_vma=not interpret,
     )(*(tuple(bufs) + tuple(upd_bufs or ())))
     return list(out)
 
@@ -595,7 +653,7 @@ def _mix_pytree_model_sharded(params, updates, spec, mesh, param_specs,
         bufs = pack(local, layout, lead_ndim=0, shard_index=s)
         upd_bufs = None if u_loc is None else pack(u_loc, layout, lead_ndim=0,
                                                    shard_index=s)
-        ici_gather = lambda x: jax.lax.all_gather(x, spec.model_axis)
+        ici_gather = lambda x: all_gather_invariant(x, spec.model_axis)
         outs, gathered = [], []
         for gi, g in enumerate(layout.groups):
             u2 = None if upd_bufs is None else upd_bufs[gi]
@@ -620,17 +678,37 @@ def _mix_pytree_model_sharded(params, updates, spec, mesh, param_specs,
         return jax.tree.map(lambda x: x[None], mixed)
 
     if updates is None:
-        return compat.shard_map(
+        return jax.shard_map(
             lambda p: f(p, None), mesh=mesh, in_specs=(param_specs,),
-            out_specs=param_specs, axis_names=manual)(params)
-    return compat.shard_map(
+            out_specs=param_specs, axis_names=manual,
+            check_vma=not interpret)(params)
+    return jax.shard_map(
         f, mesh=mesh, in_specs=(param_specs, param_specs),
-        out_specs=param_specs, axis_names=manual)(params, updates)
+        out_specs=param_specs, axis_names=manual,
+        check_vma=not interpret)(params, updates)
+
+
+def _permute_workers(x: jax.Array, perm) -> jax.Array:
+    """``x[perm]`` along the leading worker dim, as static slices.
+
+    A static permutation is a few contiguous runs (two for a ring shift);
+    slicing them keeps XLA from lowering an indexed gather, which the TPU
+    compiler splits into thousands of small gathers on a multi-GB bus.
+    """
+    perm = [int(p) for p in perm]
+    runs, start = [], 0
+    for j in range(1, len(perm) + 1):
+        if j == len(perm) or perm[j] != perm[j - 1] + 1:
+            runs.append(jax.lax.slice_in_dim(x, perm[start],
+                                             perm[j - 1] + 1, axis=0))
+            start = j
+    return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=0)
 
 
 def _mix_buffers_local(bufs, upd_bufs, weights, eta, perms, nchunks,
-                       interpret, donate, groups, block_c):
-    """Single-process emulation: permutation = row gather on the worker dim.
+                       interpret, groups, block_c):
+    """Single-process emulation: the kernel reads each permutation's
+    neighbor rows in place from the other workers' rows of the buffer.
 
     Numerically identical to the sharded path — same kernel, same summation
     order — and mirrors its chunking (each chunk of rows runs through its
@@ -644,18 +722,18 @@ def _mix_buffers_local(bufs, upd_bufs, weights, eta, perms, nchunks,
         for start, size in chunks:
             x_c = jax.lax.slice_in_dim(x, start, start + size, axis=1)
             w2 = x_c.reshape(M * size, g.cols)
-            nbrs = jnp.stack([
-                x_c[np.asarray(perm)].reshape(M * size, g.cols)
-                for _, perm in perms])
             u2 = None
             if upd_bufs is not None:
                 u2 = jax.lax.slice_in_dim(
                     upd_bufs[gi], start, start + size, axis=1
                 ).reshape(M * size, g.cols)
+            # neighbors are read in place from w2: row block of worker m
+            # for permutation p comes from worker perm[m]
             pieces.append(gossip_mix_2d(
-                w2, nbrs, weights, u2, eta,
+                w2, [w2] * len(perms), weights, u2, eta,
                 block_r=min(g.block_r, size), block_c=block_c,
-                interpret=interpret, donate=donate).reshape(M, size, g.cols))
+                interpret=interpret,
+                sources=[perm for _, perm in perms]).reshape(M, size, g.cols))
         outs.append(pieces[0] if len(pieces) == 1 else
                     jnp.concatenate(pieces, 1))
     return outs
@@ -690,6 +768,9 @@ def mix_bus(params: PyTree, spec, mesh=None, *, updates: PyTree | None = None,
 
     ``interpret=None`` (default) auto-selects: the compiled Pallas kernel on
     TPU, interpret (Python-emulation, correctness-only) mode elsewhere.
+    The compiled path keeps ``jax.shard_map``'s vma check on; interpret mode
+    turns it off, because the Pallas interpreter (JAX 0.9) re-evaluates the
+    kernel body with literals that carry no vma, which the check rejects.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -711,8 +792,7 @@ def mix_bus(params: PyTree, spec, mesh=None, *, updates: PyTree | None = None,
             lambda b, u: (b * weights[0] - eta_arr[0] * u).astype(b.dtype),
             params, updates)
 
-    if mesh is None:
-        mesh = compat.get_current_mesh()
+    mesh = _ambient_mesh(mesh)
     if mesh is not None and param_specs is not None:
         with tel.annotate("bus.fused_mix"):
             return _mix_pytree_model_sharded(params, updates, spec, mesh,
@@ -740,8 +820,7 @@ def mix_bus(params: PyTree, spec, mesh=None, *, updates: PyTree | None = None,
         else:
             mixed = _mix_buffers_local(bufs, upd_bufs, weights, eta_arr,
                                        others, nchunks, interpret,
-                                       donate=False, groups=layout.groups,
-                                       block_c=block_c)
+                                       groups=layout.groups, block_c=block_c)
     return unpack(mixed, layout)
 
 
@@ -783,7 +862,7 @@ def _mix_buffers_local_compressed(bufs, res_bufs, weights, perms, groups,
         if wt is None:   # exact group: int/bool state never quantizes
             acc = x.astype(jnp.float32) * weights[0]
             for i, (_, perm) in enumerate(perms):
-                acc += x[np.asarray(perm)].astype(jnp.float32) * weights[i + 1]
+                acc += _permute_workers(x, perm).astype(jnp.float32) * weights[i + 1]
             outs.append(acc.astype(g.dtype))
             new_res.append(None)
             continue
@@ -796,7 +875,7 @@ def _mix_buffers_local_compressed(bufs, res_bufs, weights, perms, groups,
             deq = _dequant_f32(v, s)
         acc = deq * weights[0]
         for i, (_, perm) in enumerate(perms):
-            acc += deq[np.asarray(perm)] * weights[i + 1]
+            acc += _permute_workers(deq, perm) * weights[i + 1]
         outs.append(acc.astype(g.dtype))
         new_res.append(xe - deq)
     return outs, new_res
@@ -849,10 +928,10 @@ def _mix_buffers_sharded_compressed(bufs, res_bufs, spec, mesh, weights,
         return tuple(outs) + tuple(news)
 
     n_res = len(res_in)
-    out = compat.shard_map(
+    out = jax.shard_map(
         f, mesh=mesh, in_specs=in_specs,
         out_specs=tuple(P(spec.worker_axes) for _ in range(n + n_res)),
-        axis_names=set(spec.worker_axes),
+        axis_names=set(spec.worker_axes), check_vma=not interpret,
     )(*(tuple(bufs) + tuple(res_in)))
     mixed = list(out[:n])
     news = iter(out[n:])
@@ -911,8 +990,7 @@ def mix_bus_compressed(params: PyTree, spec, mesh=None, *, wire_dtype,
                     for b, wt in zip(bufs, wts)]
     assert len(res_bufs) == len(bufs), "residual does not match the layout"
 
-    if mesh is None:
-        mesh = compat.get_current_mesh()
+    mesh = _ambient_mesh(mesh)
     with tel.annotate("bus.compressed_mix"):
         if mesh is not None:
             mixed, new_res = _mix_buffers_sharded_compressed(

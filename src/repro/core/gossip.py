@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.topology import Topology
 
 __all__ = ["GossipSpec", "mix_pytree", "mix_reference", "make_mixer",
@@ -181,7 +180,7 @@ def _shard_map_mix(params: PyTree, spec: GossipSpec, mesh, leaf_fn,
     def f(p):
         return jax.tree.map(leaf_fn, p)
 
-    return compat.shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=(specs,),
@@ -211,9 +210,12 @@ def mix_pytree(params: PyTree, spec: GossipSpec, mesh=None, *,
         # (numerically identical to the sharded path, same fused kernel).
         return bus.mix_bus(params, spec, mesh, param_specs=param_specs)
     if mesh is None:
-        mesh = compat.get_current_mesh()
-        if mesh is None:  # pragma: no cover - interactive use
-            return _einsum_mix(params, spec)
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            raise ValueError(
+                f"gossip backend {backend!r} runs collectives over the worker "
+                f"axes {spec.worker_axes}, and no mesh is set: pass mesh= or "
+                "run under jax.set_mesh (backend='einsum' runs meshless)")
     if backend == "allreduce":
         return _shard_map_mix(
             params, spec, mesh, lambda x: _allreduce_leaf(x, spec.worker_axes),

@@ -110,7 +110,8 @@ def flash_attention(
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Lq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Lq, hd), q.dtype,
+                                       vma=jax.typeof(q).vma),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),

@@ -13,8 +13,15 @@ from repro.kernels.flash_attention.kernel import flash_attention
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret",
                                              "block_q", "block_kv"))
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
-              interpret: bool = True, block_q: int = 512, block_kv: int = 512):
-    """q: (B, Lq, H, hd); k/v: (B, Lkv, Hkv, hd) -> (B, Lq, H, hd)."""
+              interpret: bool | None = None, block_q: int = 512,
+              block_kv: int = 512):
+    """q: (B, Lq, H, hd); k/v: (B, Lkv, Hkv, hd) -> (B, Lq, H, hd).
+
+    ``interpret=None`` compiles the kernel on TPU and interprets it
+    elsewhere.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

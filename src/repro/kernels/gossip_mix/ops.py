@@ -10,8 +10,8 @@ Two entry points:
   tiles with a lane-padded tail) and runs ONE kernel call per dtype group,
   instead of the old per-leaf Python loop of pad/stack/kernel dispatches.
 
-`interpret=True` (default, for CPU) executes the kernel body in Python for
-validation; on TPU pass interpret=False.
+``interpret=None`` (default) compiles the kernel on TPU and runs it in
+Pallas interpret mode elsewhere, as the bus does.
 """
 from __future__ import annotations
 
@@ -39,13 +39,15 @@ def _pad_to_2d(x: jax.Array, block_r: int, block_c: int):
 @functools.partial(jax.jit, static_argnames=("interpret", "block_r", "block_c"))
 def gossip_mix_leaf(
     w: jax.Array, neighbors: jax.Array, weights: jax.Array, update: jax.Array,
-    eta, *, interpret: bool = True,
+    eta, *, interpret: bool | None = None,
     block_r: int = DEFAULT_BLOCK_R, block_c: int = DEFAULT_BLOCK_C,
 ) -> jax.Array:
     """Fused mix+update for one leaf of any shape. neighbors: (k, *w.shape)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     k = neighbors.shape[0]
     w2, n = _pad_to_2d(w, block_r, block_c)
-    nb2 = jnp.stack([_pad_to_2d(neighbors[d], block_r, block_c)[0] for d in range(k)])
+    nb2 = [_pad_to_2d(neighbors[d], block_r, block_c)[0] for d in range(k)]
     up2, _ = _pad_to_2d(update, block_r, block_c)
     out = gossip_mix_2d(
         w2, nb2, weights.astype(jnp.float32),
@@ -56,13 +58,16 @@ def gossip_mix_leaf(
 
 def gossip_mix_pytree(params: PyTree, neighbor_params: list[PyTree],
                       weights: jax.Array, updates: PyTree, eta,
-                      *, interpret: bool = True,
+                      *, interpret: bool | None = None,
                       block_r: int = DEFAULT_BLOCK_R,
                       block_c: int = DEFAULT_BLOCK_C) -> PyTree:
     """Fused kernel over a pytree via the flat bus layout (one pack, one
     kernel dispatch per dtype group — not one per leaf). Uses the cached
     layout-v2 plan with a single shard (shards=1: every leaf packs whole)."""
     from repro.core import bus
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
 
     layout = bus.plan_layout(params, lead_ndim=0, block_r=block_r)
     self_bufs = bus.pack(params, layout, lead_ndim=0)
@@ -72,7 +77,7 @@ def gossip_mix_pytree(params: PyTree, neighbor_params: list[PyTree],
     eta_arr = jnp.asarray([eta], jnp.float32)
     outs = []
     for gi, g in enumerate(layout.groups):
-        nbrs = jnp.stack([nb[gi] for nb in nbr_bufs])
+        nbrs = [nb[gi] for nb in nbr_bufs]
         outs.append(gossip_mix_2d(
             self_bufs[gi], nbrs, weights, upd_bufs[gi], eta_arr,
             block_r=g.block_r, block_c=block_c, interpret=interpret))

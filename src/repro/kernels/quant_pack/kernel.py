@@ -53,6 +53,7 @@ def quantize_pack_2d(
     block_r = min(block_r, R)
     assert R % block_r == 0, (R, block_r)
     grid = (R // block_r,)
+    vma = jax.typeof(x).vma     # manual axes the input varies over
     values, scales = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -62,8 +63,8 @@ def quantize_pack_2d(
             pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((R, C), jnp.int8),
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, C), jnp.int8, vma=vma),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32, vma=vma),
         ],
         interpret=interpret,
     )(x)

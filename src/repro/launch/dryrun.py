@@ -11,8 +11,11 @@ Writes JSON artifacts to results/dryrun/.
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # ^ MUST precede any jax import: jax locks the device count on first init.
 #   (setdefault so tests can pre-set a smaller count before importing us.)
+#   The dry-run models the production mesh on forced host devices, so it
+#   and its --all children stay on the CPU and never ask for a chip.
 
 import argparse
 import dataclasses
@@ -202,8 +205,7 @@ def build_and_compile(arch: str, shape_name: str, *, multi_pod: bool = False,
     wa = wm.worker_axes
     t0 = time.time()
 
-    from repro import compat
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         defs = M.model_defs(cfg)
         params_abs = abstract_tree(defs, jnp.dtype(cfg.param_dtype))
         ins = input_specs(cfg, shape_name, wm, mode)
@@ -236,8 +238,8 @@ def build_and_compile(arch: str, shape_name: str, *, multi_pod: bool = False,
             batch_spec = {k: batch_spec[k] for k in ins}
             fn = jax.jit(
                 step,
-                in_shardings=compat.to_shardings(mesh, (state_spec, batch_spec)),
-                out_shardings=compat.to_shardings(mesh, (state_spec, None)),
+                in_shardings=(state_spec, batch_spec),
+                out_shardings=(state_spec, None),
                 donate_argnums=(0,) if donate else ())
             lowered = fn.lower(state_abs, ins)
             n_tokens = spec["global_batch"] * spec["seq_len"]
@@ -262,7 +264,7 @@ def build_and_compile(arch: str, shape_name: str, *, multi_pod: bool = False,
                 args = (params_abs, ins["tokens"])
                 in_sh = (pspec, P(b_ax, None))
             fn = jax.jit(fn_prefill,
-                         in_shardings=compat.to_shardings(mesh, in_sh))
+                         in_shardings=in_sh)
             lowered = fn.lower(*args)
             n_tokens = spec["global_batch"] * spec["seq_len"]
         else:  # decode
@@ -274,16 +276,16 @@ def build_and_compile(arch: str, shape_name: str, *, multi_pod: bool = False,
             b_ax = shard_lib._div(gb, mesh, wa[0] if len(wa) == 1 else wa)
             if cfg.encoder_layers:
                 ckv_spec = shard_lib.cross_kv_pspecs(cfg, mesh, gb)
-                fn = jax.jit(serve, in_shardings=compat.to_shardings(mesh, (
-                    pspec, cache_spec, P(b_ax, None), P(b_ax, None, None), ckv_spec)),
-                    out_shardings=compat.to_shardings(mesh, (None, cache_spec)),
+                fn = jax.jit(serve, in_shardings=(
+                    pspec, cache_spec, P(b_ax, None), P(b_ax, None, None), ckv_spec),
+                    out_shardings=(None, cache_spec),
                     donate_argnums=(1,) if donate else ())
                 lowered = fn.lower(params_abs, ins["caches"], ins["tokens"],
                                    ins["memory"], ins["cross_kvs"])
             else:
-                fn = jax.jit(serve, in_shardings=compat.to_shardings(mesh, (
-                    pspec, cache_spec, P(b_ax, None))),
-                    out_shardings=compat.to_shardings(mesh, (None, cache_spec)),
+                fn = jax.jit(serve, in_shardings=(
+                    pspec, cache_spec, P(b_ax, None)),
+                    out_shardings=(None, cache_spec),
                     donate_argnums=(1,) if donate else ())
                 lowered = fn.lower(params_abs, ins["caches"], ins["tokens"])
             n_tokens = spec["global_batch"]  # one token per sequence
@@ -295,7 +297,9 @@ def build_and_compile(arch: str, shape_name: str, *, multi_pod: bool = False,
             mem_str = str(mem)
         except Exception as e:  # pragma: no cover
             mem_str = f"unavailable: {e}"
+        # the production mesh is a v5e pod, modelled on host devices
         terms = roof_lib.analyze(compiled, cfg, chips=chips, n_tokens=n_tokens,
+                                 device_kind=roof_lib.V5E,
                                  kind="train" if kind == "train" else "serve")
         from repro.launch import hlo_cost as hc_lib
         hlo = compiled.as_text()

@@ -66,12 +66,15 @@ class Computation:
 
 
 _COMMENT_RE = re.compile(r"/\*.*?\*/")
+# TPU layouts carry tiling and memory-space parentheses after the shape
+# (`bf16[8,128]{1,0:T(8,128)(2,1)S(1)}`), which would read as an opcode
+_LAYOUT_RE = re.compile(r"(\])\{[^{}]*\}")
 
 
 def parse_computations(hlo: str) -> dict[str, Computation]:
     comps: dict[str, Computation] = {}
     cur: Computation | None = None
-    hlo = _COMMENT_RE.sub("", hlo)
+    hlo = _LAYOUT_RE.sub(r"\1", _COMMENT_RE.sub("", hlo))
     for line in hlo.splitlines():
         mc = _COMP_RE.match(line)
         if mc and ("->" in line):

@@ -21,7 +21,6 @@ from typing import Any
 import jax
 import numpy as np
 
-from repro import compat
 
 SINGLE_POD = (16, 16)                  # 256 chips
 MULTI_POD = (2, 16, 16)                # 2 pods × 256 chips = 512
@@ -190,8 +189,8 @@ class WorkerMesh:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(
-        shape, axes, axis_types=(compat.AxisType.Auto,) * len(axes))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_worker_mesh(*, multi_pod: bool = False) -> WorkerMesh:
@@ -204,11 +203,11 @@ def make_host_mesh(data: int = 2, model: int = 2, pod: int | None = None):
     n = len(jax.devices())
     if pod:
         assert pod * data * model <= n
-        return compat.make_mesh((pod, data, model), ("pod", "data", "model"),
-                                axis_types=(compat.AxisType.Auto,) * 3)
+        return jax.make_mesh((pod, data, model), ("pod", "data", "model"),
+                                axis_types=(jax.sharding.AxisType.Auto,) * 3)
     assert data * model <= n, (data, model, n)
-    return compat.make_mesh((data, model), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return jax.make_mesh((data, model), ("data", "model"),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def worker_axes(mesh) -> tuple[str, ...]:

@@ -1,10 +1,11 @@
 """Roofline analysis from AOT-compiled artifacts (no hardware execution).
 
-Three terms per (arch × shape × mesh), from the dry-run:
+Three terms per (arch × shape × mesh), from the dry-run, against the peaks
+of the chip the program targets (:data:`PEAKS`, keyed by ``device_kind``):
 
-    compute   = HLO_FLOPs          / (chips × 197e12 FLOP/s bf16)
-    memory    = HLO_bytes_accessed / (chips × 819e9  B/s HBM)
-    collective= collective_bytes   / (chips × 50e9   B/s ICI link)
+    compute   = HLO_FLOPs          / peak bf16 FLOP/s
+    memory    = HLO_bytes_accessed / peak HBM B/s
+    collective= collective_bytes   / one ICI link's B/s
 
 HLO_FLOPs / bytes come from ``compiled.cost_analysis()``.  collective_bytes
 is parsed from the compiled HLO text: we sum the *result* byte sizes of every
@@ -25,10 +26,33 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 
-# TPU v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # B/s
-ICI_BW = 50e9              # B/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float          # FLOP/s
+    hbm_bytes_per_s: float
+    ici_link_bytes_per_s: float
+
+
+# Published per-chip peaks, keyed by jax ``Device.device_kind``.
+# TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GiB HBM at 819 GB/s, 1,600 Gbit/s of ICI per chip over 4 links
+# (50 GB/s per link).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: ChipPeaks(bf16_flops=197e12, hbm_bytes_per_s=819e9,
+                   ici_link_bytes_per_s=50e9),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; an unknown chip raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -108,18 +132,19 @@ class RooflineTerms:
     chips: int
     n_tokens: int
     model_flops_total: float   # 6·N·D (whole step, all chips)
+    device_kind: str           # key into PEAKS
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind).bf16_flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / peaks(self.device_kind).hbm_bytes_per_s
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / peaks(self.device_kind).ici_link_bytes_per_s
 
     @property
     def bottleneck(self) -> str:
@@ -146,11 +171,12 @@ class RooflineTerms:
             "useful_flops_ratio": self.useful_flops_ratio,
             "chips": self.chips,
             "n_tokens": self.n_tokens,
+            "device_kind": self.device_kind,
         }
 
 
 def analyze(compiled, cfg: ModelConfig, *, chips: int, n_tokens: int,
-            kind: str = "train") -> RooflineTerms:
+            device_kind: str, kind: str = "train") -> RooflineTerms:
     """Roofline terms from the compiled artifact.
 
     Uses the trip-count-aware HLO cost model (``repro.launch.hlo_cost``):
@@ -165,4 +191,5 @@ def analyze(compiled, cfg: ModelConfig, *, chips: int, n_tokens: int,
     return RooflineTerms(
         flops=hc.flops, bytes_accessed=hc.bytes, coll_bytes=hc.coll_bytes["total"],
         chips=chips, n_tokens=n_tokens,
-        model_flops_total=model_flops(cfg, n_tokens, kind))
+        model_flops_total=model_flops(cfg, n_tokens, kind),
+        device_kind=device_kind)

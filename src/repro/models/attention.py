@@ -299,10 +299,8 @@ def _is_ring(cache: KVCache, window: int | None) -> bool:
 def _seq_sharded_cache(cache_k: jax.Array) -> bool:
     """True when the decode cache is sequence-sharded over 'model' (KV heads
     don't divide the model axis — see launch.shardings.cache_pspecs)."""
-    from repro import compat
-
-    mesh = compat.get_current_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return False
     msize = mesh.shape["model"]
     return (msize > 1 and cache_k.shape[2] % msize != 0

@@ -118,10 +118,8 @@ def _cap_shard(buf: jax.Array) -> jax.Array:
     on the 2.5x-expanded buffer). §Perf hillclimb B."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
-    mesh = compat.get_current_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return buf
     if buf.shape[-2] % mesh.shape["model"]:
         return buf
@@ -164,11 +162,8 @@ def moe_apply(
         # otherwise — §Perf hillclimb B it3). Expert weights stay 'model'-auto.
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
-
-        mesh = compat.get_current_mesh()
-        wa = tuple(a for a in (mesh.axis_names if mesh is not None else ())
-                   if a != "model")
+        mesh = jax.sharding.get_abstract_mesh()
+        wa = tuple(a for a in mesh.axis_names if a != "model")
         n_shards = 1
         for a in wa:
             n_shards *= mesh.shape[a]
@@ -179,7 +174,7 @@ def moe_apply(
                 y, aux = jax.vmap(lambda s: _moe_tokens(params, cfg, s))(xb)
                 return y, jax.lax.pmean(aux.mean(), wa)
 
-            y, aux = compat.shard_map(f, mesh=mesh, in_specs=(spec,),
+            y, aux = jax.shard_map(f, mesh=mesh, in_specs=(spec,),
                                       out_specs=(spec, P()),
                                       axis_names=set(wa))(x)
             if cfg.n_shared_experts:

@@ -271,10 +271,8 @@ def _act_shard(x, cfg: ModelConfig):
         return x
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
-    mesh = compat.get_current_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return x
     if mode == "batch":
         wa = tuple(a for a in mesh.axis_names if a != "model")

@@ -14,7 +14,7 @@ from typing import Any, Callable, Iterable
 import jax
 import numpy as np
 
-from repro import compat, telemetry
+from repro import telemetry
 from repro.core.decentralized import StepMetrics, TrainState, init_state, make_train_step
 from repro.core.gossip import GossipSpec
 from repro.optim import Optimizer
@@ -145,7 +145,7 @@ def train(
     if ckpt_sharded:
         ckpt_kw = dict(sharded=True,
                        wmesh=mesh if isinstance(mesh, WorkerMesh) else None)
-    ctx = compat.set_mesh(raw_mesh) if raw_mesh is not None else _nullcontext()
+    ctx = jax.set_mesh(raw_mesh) if raw_mesh is not None else _nullcontext()
     try:
         with ctx:
             for k in range(steps):

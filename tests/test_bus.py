@@ -321,10 +321,9 @@ def test_sharded_fused_collective_count_and_numerics():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T, bus
 from repro.core.gossip import GossipSpec, mix_pytree, mix_pytree_reference
-mesh = compat.make_mesh((4,2), ("data","model"))
+mesh = jax.make_mesh((4,2), ("data","model"))
 key = jax.random.PRNGKey(0)
 params = {"w": jax.random.normal(key, (4, 37, 5)),
           "b": jnp.ones((4, 3)), "c": jax.random.normal(key, (4, 257))}
@@ -333,7 +332,7 @@ for topo in [T.undirected_ring(4), T.clique(4), T.directed_ring_lattice(4, 2)]:
     spec = GossipSpec(topology=topo, backend="fused", worker_axes=("data",))
     expect = bus.bulk_collectives_per_step(spec)
     ref = mix_pytree_reference(params, topo.A)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sh = jax.NamedSharding(mesh, P("data"))
         p = jax.tree.map(lambda x: jax.device_put(x, sh), params)
         f = jax.jit(lambda q: mix_pytree(q, spec, mesh))
@@ -360,7 +359,6 @@ def test_model_sharded_bus_bytes_drop_by_k():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T, bus
 from repro.core.gossip import GossipSpec, mix_pytree_reference
 from repro.launch.hlo_cost import analyze_hlo
@@ -374,8 +372,8 @@ topo = T.undirected_ring(M)
 ref = mix_pytree_reference(params, topo.A)
 stats = {}
 for k in (1, 2):
-    mesh = compat.make_mesh((M, k), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2,
+    mesh = jax.make_mesh((M, k), ("data", "model"),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 2,
                             devices=jax.devices()[: M * k])
     spec = GossipSpec(topology=topo, backend="fused", worker_axes=("data",),
                       model_axis="model" if k > 1 else None)
@@ -383,7 +381,7 @@ for k in (1, 2):
     pspecs = {"w": P("data", None, m_ax, None),
               "emb": P("data", None, m_ax),
               "v": P("data", None, None)}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p = jax.tree.map(lambda x, s: jax.device_put(
             x, jax.NamedSharding(mesh, s)), params, pspecs)
         f = jax.jit(lambda q: bus.mix_bus(q, spec, mesh, param_specs=pspecs))
@@ -411,7 +409,6 @@ def test_model_sharded_fused_train_step_matches_meshless():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T
 from repro.core.gossip import GossipSpec
 from repro.core.decentralized import make_train_step, init_state, replicate_for_workers
@@ -435,7 +432,7 @@ for _ in range(10):
 wm = WorkerMesh.from_mesh(make_host_mesh(data=4, model=2))
 spec = GossipSpec.for_mesh(topo, wm, backend="fused")
 pspecs = {"x": P("data", "model")}
-with compat.set_mesh(wm.mesh):
+with jax.set_mesh(wm.mesh):
     s1 = init_state(replicate_for_workers({"x": jnp.zeros(8)}, M), opt)
     step1 = jax.jit(make_train_step(loss, opt, gossip=spec, mode="gossip",
                                     mesh=wm, param_specs=pspecs))

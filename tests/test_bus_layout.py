@@ -260,7 +260,6 @@ def test_row_split_gather_count_unchanged_under_chunking_hlo():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T, bus
 from repro.core.gossip import GossipSpec, mix_pytree_reference
 from repro.launch.hlo_cost import analyze_hlo
@@ -273,10 +272,10 @@ pspecs = {"w": P("data", None, "model"), "kv": P("data", None, None)}
 topo = T.directed_ring_lattice(M, 1)
 spec = GossipSpec(topology=topo, backend="fused", worker_axes=("data",),
                   model_axis="model")
-mesh = compat.make_mesh((M, k), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((M, k), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ref = mix_pytree_reference(params, topo.A)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     p = jax.tree.map(lambda x, s: jax.device_put(
         x, jax.NamedSharding(mesh, s)), params, pspecs)
     for nchunks in (1, 3):
@@ -314,7 +313,6 @@ def test_gqa_cp_bytes_hit_ideal_over_k_hlo():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T, bus
 from repro.core.gossip import GossipSpec, mix_pytree_reference
 from repro.launch.hlo_cost import analyze_hlo
@@ -330,8 +328,8 @@ payload = sum(x.size // M for x in params.values()) * 4    # bytes / worker
 topo = T.directed_ring_lattice(M, 1)                       # degree 1: 1 cp
 ref = mix_pytree_reference(params, topo.A)
 for k in (4, 16):
-    mesh = compat.make_mesh((M, k), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2,
+    mesh = jax.make_mesh((M, k), ("data", "model"),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 2,
                             devices=jax.devices()[: M * k])
     spec = GossipSpec(topology=topo, backend="fused", worker_axes=("data",),
                       model_axis="model")
@@ -345,7 +343,7 @@ for k in (4, 16):
     flags = bus.sharded_leaf_flags(pspecs, "model")
     layout = bus.plan_layout(local, lead_ndim=0, shards=k, leaf_sharded=flags)
     expect = layout.padded_bytes()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p = jax.tree.map(lambda x, s: jax.device_put(
             x, jax.NamedSharding(mesh, s)), params, pspecs)
         f = jax.jit(lambda q: bus.mix_bus(q, spec, mesh, param_specs=pspecs))

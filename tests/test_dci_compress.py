@@ -445,7 +445,6 @@ def test_compressed_mix_cp_bytes_match_layout_prediction_hlo():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import topology as T, bus
 from repro.core.gossip import GossipSpec
 from repro.launch.hlo_cost import analyze_hlo
@@ -456,11 +455,11 @@ params = {"w": jax.random.normal(key, (M, 127)),
           "b": jax.random.normal(key, (M, 33, 5))}
 topo = T.undirected_ring(M)
 spec = GossipSpec(topology=topo, backend="fused", worker_axes=("data",))
-mesh = compat.make_mesh((M,), ("data",),
-                        axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((M,), ("data",),
+                        axis_types=(jax.sharding.AxisType.Auto,))
 layout = bus.plan_layout(params, lead_ndim=1, block_r=32)
 n_perms = len(bus._split_perms(spec)[1])
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     p = jax.tree.map(lambda x: jax.device_put(
         x, jax.NamedSharding(mesh, P("data"))), params)
     f = jax.jit(lambda q: bus.mix_bus_compressed(

@@ -194,3 +194,11 @@ def test_time_varying_one_peer_gossip():
                        np.asarray(targets.mean(0)), atol=0.5)
     # degree-1 mixing per step => larger residual spread than the static ring
     assert float(m.param_spread) < 15.0
+
+
+@pytest.mark.parametrize("backend", ["ppermute", "allreduce"])
+def test_collective_backend_without_mesh_raises(backend):
+    """A collective backend never falls back to the dense mix in silence."""
+    spec = GossipSpec(topology=T.undirected_ring(4), backend=backend)
+    with pytest.raises(ValueError, match="no mesh is set"):
+        mix_pytree({"w": jnp.ones((4, 3))}, spec)

@@ -10,13 +10,12 @@ def test_ppermute_gossip_matches_dense_oracle():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import topology as T, gossip as G
-from repro import compat
-mesh = compat.make_mesh((4,2), ("data","model"), axis_types=(compat.AxisType.Auto,)*2)
+mesh = jax.make_mesh((4,2), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
 for topo in [T.undirected_ring(4), T.clique(4), T.directed_ring_lattice(4,2), T.hypercube(2)]:
     spec = G.GossipSpec(topology=topo, backend="ppermute", worker_axes=("data",))
     params = {"w": jnp.arange(4*6, dtype=jnp.float32).reshape(4,6), "b": jnp.ones((4,3))}
     ref = G.mix_pytree_reference(params, topo.A)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sh = jax.NamedSharding(mesh, P("data"))
         p = jax.tree.map(lambda x: jax.device_put(x, sh), params)
         out = jax.jit(lambda q: G.mix_pytree(q, spec, mesh))(p)
@@ -33,13 +32,12 @@ def test_multipod_gossip_over_two_axes():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import topology as T, gossip as G
-from repro import compat
-mesh = compat.make_mesh((2,2,2), ("pod","data","model"), axis_types=(compat.AxisType.Auto,)*3)
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), axis_types=(jax.sharding.AxisType.Auto,)*3)
 topo = T.undirected_ring(4)
 spec = G.GossipSpec(topology=topo, backend="ppermute", worker_axes=("pod","data"))
 x = {"w": jnp.arange(4*4, dtype=jnp.float32).reshape(4,4)}
 ref = G.mix_pytree_reference(x, topo.A)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     sh = jax.NamedSharding(mesh, P(("pod","data")))
     p = jax.tree.map(lambda v: jax.device_put(v, sh), x)
     out = jax.jit(lambda q: G.mix_pytree(q, spec, mesh))(p)
@@ -58,12 +56,11 @@ from repro.core import topology as T
 from repro.core.gossip import GossipSpec
 from repro.core.decentralized import make_train_step, init_state, replicate_for_workers
 from repro.optim import momentum_sgd
-from repro import compat
-mesh = compat.make_mesh((4,2), ("data","model"), axis_types=(compat.AxisType.Auto,)*2)
+mesh = jax.make_mesh((4,2), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
 def loss(p, b): return jnp.mean((p["x"] - b)**2)
 targets = jnp.tile(jnp.asarray([[1.,2.]]), (4,1))
 opt = momentum_sgd(0.1, 0.9)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     sA = init_state(replicate_for_workers({"x": jnp.zeros(2)}, 4), opt)
     stepA = jax.jit(make_train_step(loss, opt,
         gossip=GossipSpec(topology=T.clique(4), backend="ppermute", worker_axes=("data",)),

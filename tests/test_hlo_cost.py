@@ -91,3 +91,23 @@ def test_model_flops_moe_active_only():
     assert 10e9 < n_active < 20e9          # ~13B active of 47B total
     assert n_active < mix.n_params() * 0.4
     assert model_flops(mix, 1000, "train") == 6.0 * n_active * 1000
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    from repro.launch.roofline import V5E, peaks
+
+    assert peaks(V5E).bf16_flops == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_hlo_parser_reads_tpu_layouts():
+    """TPU HLO annotates shapes with tiling and memory-space parentheses;
+    the parser must still see the collective as the opcode."""
+    hlo = """ENTRY %main (p: bf16[8,128]) -> bf16[8,128] {
+  %p = bf16[8,128]{1,0:T(8,128)(2,1)} parameter(0)
+  %cp = (bf16[8,128]{1,0:T(8,128)(2,1)S(1)}, bf16[8,128]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) collective-permute-start(%p), source_target_pairs={{0,1},{1,0}}
+  ROOT %d = bf16[8,128]{1,0:T(8,128)(2,1)} collective-permute-done(%cp)
+}
+"""
+    assert analyze_hlo(hlo).coll_counts["collective-permute"] == 1

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import topology as T
 from repro.core.decentralized import make_train_step
 from repro.core.gossip import GossipSpec
@@ -15,13 +14,13 @@ from repro.optim import sgd
 
 
 def _mesh11():
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return jax.make_mesh((1, 1), ("data", "model"),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _mesh111():
-    return compat.make_mesh((1, 1, 1), ("pod", "data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 3)
+    return jax.make_mesh((1, 1, 1), ("pod", "data", "model"),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 
 def test_from_mesh_factors_out_model_axis():
@@ -42,8 +41,8 @@ def test_from_mesh_multipod_worker_axes():
 
 
 def test_from_mesh_without_model_axis():
-    mesh = compat.make_mesh((1,), ("data",),
-                            axis_types=(compat.AxisType.Auto,))
+    mesh = jax.make_mesh((1,), ("data",),
+                            axis_types=(jax.sharding.AxisType.Auto,))
     wm = WorkerMesh.from_mesh(mesh)
     assert wm.model_axis is None and wm.model_factor == 1
     assert wm.worker_axes == ("data",)
