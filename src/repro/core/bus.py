@@ -640,41 +640,36 @@ def _mix_pytree_model_sharded(params, updates, spec, mesh, param_specs,
                                    treedef=jax.tree.structure(p))
         layout = plan_layout(local, lead_ndim=0, block_r=block_r,
                              shards=k, leaf_sharded=flags)
-        tel = telemetry.get()
-        if tel.active:
-            # trace-time emit (the shard_map body traces once per compile):
-            # per-shard wire bytes + the one-ICI-gather-per-dtype-group
-            # count of the row-split re-assembly
-            tel.gauge("bus.padded_bytes_shard", layout.padded_bytes())
-            tel.counter("bus.all_gathers", sum(
-                1 for g in layout.groups
-                if k > 1 and g.split_off < g.split_end))
         s = jax.lax.axis_index(spec.model_axis) if k > 1 else 0
-        bufs = pack(local, layout, lead_ndim=0, shard_index=s)
-        upd_bufs = None if u_loc is None else pack(u_loc, layout, lead_ndim=0,
-                                                   shard_index=s)
+        with jax.named_scope("pack"):
+            bufs = pack(local, layout, lead_ndim=0, shard_index=s)
+            upd_bufs = None if u_loc is None else pack(
+                u_loc, layout, lead_ndim=0, shard_index=s)
         ici_gather = lambda x: all_gather_invariant(x, spec.model_axis)
         outs, gathered = [], []
-        for gi, g in enumerate(layout.groups):
-            u2 = None if upd_bufs is None else upd_bufs[gi]
-            if k > 1 and g.split_off < g.split_end:
-                # fold the row-split re-assembly gather into the chunk
-                # pipeline: it runs off the head chunks, overlapping the
-                # remaining chunks' fused passes (still ONE gather per group)
-                out, gat = _mix_group_chunked(
-                    bufs[gi], u2, g.rows, g.block_r, block_c, weights, eta,
-                    pairs, axes, nchunks, interpret, donate,
-                    gather=ici_gather, span=(g.split_off, g.split_end))
-                gathered.append(gat)
-            else:
-                out = _mix_group_chunked(
-                    bufs[gi], u2, g.rows, g.block_r, block_c, weights, eta,
-                    pairs, axes, nchunks, interpret, donate)
-            outs.append(out)
+        with jax.named_scope("mix"):
+            for gi, g in enumerate(layout.groups):
+                u2 = None if upd_bufs is None else upd_bufs[gi]
+                if k > 1 and g.split_off < g.split_end:
+                    # fold the row-split re-assembly gather into the chunk
+                    # pipeline: it runs off the head chunks, overlapping the
+                    # remaining chunks' fused passes (still ONE gather per
+                    # group)
+                    out, gat = _mix_group_chunked(
+                        bufs[gi], u2, g.rows, g.block_r, block_c, weights,
+                        eta, pairs, axes, nchunks, interpret, donate,
+                        gather=ici_gather, span=(g.split_off, g.split_end))
+                    gathered.append(gat)
+                else:
+                    out = _mix_group_chunked(
+                        bufs[gi], u2, g.rows, g.block_r, block_c, weights,
+                        eta, pairs, axes, nchunks, interpret, donate)
+                outs.append(out)
         gat_iter = iter(gathered)
-        mixed = unpack(outs, layout, lead_ndim=0,
-                       gather=(lambda _span: next(gat_iter)) if gathered
-                       else None)
+        with jax.named_scope("unpack"):
+            mixed = unpack(outs, layout, lead_ndim=0,
+                           gather=(lambda _span: next(gat_iter)) if gathered
+                           else None)
         return jax.tree.map(lambda x: x[None], mixed)
 
     if updates is None:
@@ -794,12 +789,12 @@ def mix_bus(params: PyTree, spec, mesh=None, *, updates: PyTree | None = None,
 
     mesh = _ambient_mesh(mesh)
     if mesh is not None and param_specs is not None:
-        with tel.annotate("bus.fused_mix"):
-            return _mix_pytree_model_sharded(params, updates, spec, mesh,
-                                             param_specs, weights, eta_arr,
-                                             others, nchunks, interpret,
-                                             donate=not interpret,
-                                             block_r=block_r, block_c=block_c)
+        # pack, mix and unpack are scoped inside the shard_map body
+        return _mix_pytree_model_sharded(params, updates, spec, mesh,
+                                         param_specs, weights, eta_arr,
+                                         others, nchunks, interpret,
+                                         donate=not interpret,
+                                         block_r=block_r, block_c=block_c)
 
     layout = plan_layout(params, lead_ndim=1, block_r=block_r)
     if tel.active:
@@ -807,11 +802,12 @@ def mix_bus(params: PyTree, spec, mesh=None, *, updates: PyTree | None = None,
         # non-identity permutation — the number the sim's per-class byte
         # accounting charges (MeshSpec.payload_bytes)
         tel.gauge("bus.padded_bytes", layout.padded_bytes())
-    bufs = pack(params, layout)
-    upd_bufs = None
-    if updates is not None:
-        upd_bufs = pack(updates, layout)
-    with tel.annotate("bus.fused_mix"):
+    with jax.named_scope("pack"):
+        bufs = pack(params, layout)
+        upd_bufs = None
+        if updates is not None:
+            upd_bufs = pack(updates, layout)
+    with jax.named_scope("mix"):
         if mesh is not None:
             mixed = _mix_buffers_sharded(bufs, upd_bufs, spec, mesh, weights,
                                          eta_arr, others, nchunks, interpret,
@@ -821,7 +817,8 @@ def mix_bus(params: PyTree, spec, mesh=None, *, updates: PyTree | None = None,
             mixed = _mix_buffers_local(bufs, upd_bufs, weights, eta_arr,
                                        others, nchunks, interpret,
                                        groups=layout.groups, block_c=block_c)
-    return unpack(mixed, layout)
+    with jax.named_scope("unpack"):
+        return unpack(mixed, layout)
 
 
 # ---------------------------------------------------------------------------
@@ -971,19 +968,16 @@ def mix_bus_compressed(params: PyTree, spec, mesh=None, *, wire_dtype,
     wts = [wire_dtype_for(g.dtype, wire_dtype) for g in layout.groups]
     tel = telemetry.get()
     if tel.active:
-        wire_b = layout.padded_bytes(wire_dtype)
         tel.counter("bus.mix_calls")
         # int8 groups ship values + scales: two collectives per permutation
         tel.counter("bus.collectives", len(others) * sum(
             0 if wt is None else (2 if wt == jnp.dtype(jnp.int8) else 1)
             for wt in wts) + len(others) * sum(1 for wt in wts if wt is None))
-        tel.gauge("bus.dci_padded_bytes", wire_b)
-        tel.gauge("bus.dci_bytes_ratio",
-                  layout.padded_bytes() / max(wire_b, 1))
     if not others:   # degenerate (M == 1): nothing rides the wire
         return params, residual
 
-    bufs = pack(params, layout)
+    with jax.named_scope("pack"):
+        bufs = pack(params, layout)
     res_bufs = residual
     if res_bufs is None:
         res_bufs = [None if wt is None else jnp.zeros(b.shape, jnp.float32)
@@ -991,7 +985,7 @@ def mix_bus_compressed(params: PyTree, spec, mesh=None, *, wire_dtype,
     assert len(res_bufs) == len(bufs), "residual does not match the layout"
 
     mesh = _ambient_mesh(mesh)
-    with tel.annotate("bus.compressed_mix"):
+    with jax.named_scope("mix"):
         if mesh is not None:
             mixed, new_res = _mix_buffers_sharded_compressed(
                 bufs, res_bufs, spec, mesh, weights, others, layout.groups,
@@ -1000,7 +994,8 @@ def mix_bus_compressed(params: PyTree, spec, mesh=None, *, wire_dtype,
             mixed, new_res = _mix_buffers_local_compressed(
                 bufs, res_bufs, weights, others, layout.groups,
                 wire_dtype, interpret)
-    return unpack(mixed, layout), new_res
+    with jax.named_scope("unpack"):
+        return unpack(mixed, layout), new_res
 
 
 def mix_and_update_time_varying(params: PyTree, spec, updates: PyTree,

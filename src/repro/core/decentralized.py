@@ -10,6 +10,11 @@ Implementation notes
   update is elementwise, and the consensus mix is the only cross-worker
   communication (see `repro.core.gossip`). Momentum is applied to the local
   subgradients as in the paper's CIFAR experiments.
+* the step's layers are named in the compiled program's op metadata by
+  plain ``jax.named_scope`` boundaries: ``model`` (forward, and backward
+  under ``transpose(``), ``optimizer``, ``stats`` and ``gossip``. A scope
+  changes no instruction, so they are always on; a profile of the step is
+  attributed to them through the executable's ``as_text()``.
 * allreduce mode: the centralized baseline the paper compares against
   (parameter server / ring all-reduce ≡ clique topology, A = 11ᵀ/M):
   params are replicated over the worker axes, XLA inserts the all-reduce.
@@ -164,14 +169,16 @@ def make_train_step(
         def step(state: TrainState, batch: PyTree) -> tuple[TrainState, StepMetrics]:
             # batch leaves: (M, per_worker_batch, ...)
             vg = jax.vmap(jax.value_and_grad(loss_fn))
-            if microbatch > 1:
-                losses, grads = _microbatched(vg, microbatch, batch_axis=1)(
-                    state.params, batch)
-            else:
-                losses, grads = vg(state.params, batch)
-            updates, opt_state = optimizer.update(
-                grads, state.opt_state, state.params, state.step
-            )
+            with jax.named_scope("model"):
+                if microbatch > 1:
+                    losses, grads = _microbatched(vg, microbatch, batch_axis=1)(
+                        state.params, batch)
+                else:
+                    losses, grads = vg(state.params, batch)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, state.opt_state, state.params, state.step
+                )
 
             def do_mix(p):
                 if gossip.time_varying:
@@ -195,30 +202,37 @@ def make_train_step(
                     return bus.mix_bus(p, gossip, mesh, updates=updates,
                                        eta=-1.0, param_specs=param_specs)
 
-                if gossip.period > 1:
-                    new_params = jax.lax.cond(
-                        state.step % gossip.period == 0,
-                        do_mix_update, apply_update, state.params)
-                else:
-                    new_params = do_mix_update(state.params)
+                with jax.named_scope("gossip"):
+                    if gossip.period > 1:
+                        new_params = jax.lax.cond(
+                            state.step % gossip.period == 0,
+                            do_mix_update, apply_update, state.params)
+                    else:
+                        new_params = do_mix_update(state.params)
             elif mix_first:
-                if gossip.period > 1:
-                    mixed = jax.lax.cond(
-                        state.step % gossip.period == 0, do_mix, lambda p: p,
-                        state.params)
-                else:
-                    mixed = do_mix(state.params)
-                new_params = apply_update(mixed)
+                with jax.named_scope("gossip"):
+                    if gossip.period > 1:
+                        mixed = jax.lax.cond(
+                            state.step % gossip.period == 0, do_mix,
+                            lambda p: p, state.params)
+                    else:
+                        mixed = do_mix(state.params)
+                with jax.named_scope("optimizer"):
+                    new_params = apply_update(mixed)
             else:
-                stepped = apply_update(state.params)
-                new_params = gossip_lib.mix_pytree(
-                    stepped, gossip, mesh, param_specs=param_specs) \
-                    if gossip.period == 1 else jax.lax.cond(
-                        state.step % gossip.period == 0, do_mix, lambda p: p, stepped)
+                with jax.named_scope("optimizer"):
+                    stepped = apply_update(state.params)
+                with jax.named_scope("gossip"):
+                    new_params = gossip_lib.mix_pytree(
+                        stepped, gossip, mesh, param_specs=param_specs) \
+                        if gossip.period == 1 else jax.lax.cond(
+                            state.step % gossip.period == 0, do_mix,
+                            lambda p: p, stepped)
 
             if compute_stats:
-                E, E_sp, H = gradient_stats(grads)
-                spread = param_spread(new_params)
+                with jax.named_scope("stats"):
+                    E, E_sp, H = gradient_stats(grads)
+                    spread = param_spread(new_params)
             else:
                 E = E_sp = H = spread = jnp.zeros((), jnp.float32)
             metrics = StepMetrics(losses.mean(), E, E_sp, H, spread)
@@ -237,19 +251,22 @@ def make_train_step(
         # over the worker axes; XLA all-reduces the gradient.
         def step(state: TrainState, batch: PyTree) -> tuple[TrainState, StepMetrics]:
             vg = jax.value_and_grad(loss_fn)
-            if microbatch > 1:
-                loss, grads = _microbatched(vg, microbatch, batch_axis=0)(
-                    state.params, batch)
-            else:
-                loss, grads = vg(state.params, batch)
-            updates, opt_state = optimizer.update(
-                grads, state.opt_state, state.params, state.step
-            )
-            new_params = jax.tree.map(
-                lambda p, u: p + u.astype(p.dtype), state.params, updates
-            )
+            with jax.named_scope("model"):
+                if microbatch > 1:
+                    loss, grads = _microbatched(vg, microbatch, batch_axis=0)(
+                        state.params, batch)
+                else:
+                    loss, grads = vg(state.params, batch)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, state.opt_state, state.params, state.step
+                )
+                new_params = jax.tree.map(
+                    lambda p, u: p + u.astype(p.dtype), state.params, updates
+                )
             z = jnp.zeros((), jnp.float32)
-            gn = _tree_sq_norm(grads)
+            with jax.named_scope("stats"):
+                gn = _tree_sq_norm(grads)
             metrics = StepMetrics(loss, gn, z, jnp.sqrt(gn), z)
             return TrainState(state.step + 1, new_params, opt_state), metrics
 

@@ -202,7 +202,8 @@ def mix_pytree(params: PyTree, spec: GossipSpec, mesh=None, *,
     if backend not in ("einsum", "fused", "allreduce", "ppermute"):
         raise ValueError(f"unknown gossip backend {backend!r}")
     if backend == "einsum":
-        return _einsum_mix(params, spec)
+        with jax.named_scope("mix"):
+            return _einsum_mix(params, spec)
     if backend == "fused":
         from repro.core import bus  # local import: bus pulls in Pallas
 
@@ -216,14 +217,10 @@ def mix_pytree(params: PyTree, spec: GossipSpec, mesh=None, *,
                 f"gossip backend {backend!r} runs collectives over the worker "
                 f"axes {spec.worker_axes}, and no mesh is set: pass mesh= or "
                 "run under jax.set_mesh (backend='einsum' runs meshless)")
-    if backend == "allreduce":
-        return _shard_map_mix(
-            params, spec, mesh, lambda x: _allreduce_leaf(x, spec.worker_axes),
-            param_specs)
-    if backend == "ppermute":
-        return _shard_map_mix(params, spec, mesh,
-                              lambda x: _ppermute_leaf(x, spec), param_specs)
-    raise ValueError(f"unknown gossip backend {backend!r}")
+    leaf_fn = (lambda x: _allreduce_leaf(x, spec.worker_axes)) \
+        if backend == "allreduce" else (lambda x: _ppermute_leaf(x, spec))
+    with jax.named_scope("mix"):     # the per-leaf path has no pack/unpack
+        return _shard_map_mix(params, spec, mesh, leaf_fn, param_specs)
 
 
 def make_mixer(spec: GossipSpec, mesh=None):

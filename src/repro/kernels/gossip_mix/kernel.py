@@ -121,4 +121,5 @@ def gossip_mix_2d(
             vma=frozenset().union(*(jax.typeof(a).vma for a in args))),
         input_output_aliases={0: 0} if donate else {},
         interpret=interpret,
+        name="gossip_mix",
     )(*args)

@@ -165,20 +165,24 @@ def _block_apply(bp: PyTree, cfg: ModelConfig, kind: str, moe: bool, x,
     parallel = cfg.parallel_block and "mlp" in bp and "cross" not in bp \
         and kind in ("attn", "local")
     if kind in ("attn", "local"):
-        if cfg.attention_type == "mla":
-            mixed, new_c = attn_lib.mla_apply(bp["mix"], cfg, h, q_base=q_base,
-                                              cache=cache, lengths=lengths,
-                                              prompt_len=prompt_len)
-        else:
-            mixed, new_c = attn_lib.gqa_apply(
-                bp["mix"], cfg, h, q_base=q_base, causal=True, window=window,
-                cache=cache, lengths=lengths, prompt_len=prompt_len)
+        with jax.named_scope("attention"):
+            if cfg.attention_type == "mla":
+                mixed, new_c = attn_lib.mla_apply(
+                    bp["mix"], cfg, h, q_base=q_base, cache=cache,
+                    lengths=lengths, prompt_len=prompt_len)
+            else:
+                mixed, new_c = attn_lib.gqa_apply(
+                    bp["mix"], cfg, h, q_base=q_base, causal=True,
+                    window=window, cache=cache, lengths=lengths,
+                    prompt_len=prompt_len)
     elif kind == "ssm":
         if lengths is not None:
             raise NotImplementedError(
                 "ragged prompts pollute mamba2 recurrent state; batch "
                 "equal-length prompts instead")
-        mixed, new_c = ssm_lib.mamba2_apply(bp["mix"], cfg, h, cache=cache)
+        with jax.named_scope("ssd"):
+            mixed, new_c = ssm_lib.mamba2_apply(bp["mix"], cfg, h,
+                                                cache=cache)
     elif kind == "rglru":
         if lengths is not None:
             raise NotImplementedError(
@@ -192,10 +196,7 @@ def _block_apply(bp: PyTree, cfg: ModelConfig, kind: str, moe: bool, x,
         # PaLM-style parallel block: attn and MLP read the same residual input
         # and their (row-parallel) outputs sum before the single TP all-reduce.
         h2 = L.rmsnorm_apply(bp["norm2"], x, cfg.norm_eps)
-        if moe:
-            y, aux = L.moe_apply(bp["mlp"], cfg, h2)
-        else:
-            y = L.mlp_apply(bp["mlp"], cfg, h2)
+        y, aux = _mlp(bp["mlp"], cfg, h2, moe)
         return x + mixed + y, new_c, aux
 
     x = x + mixed
@@ -216,12 +217,17 @@ def _block_apply(bp: PyTree, cfg: ModelConfig, kind: str, moe: bool, x,
 
     if "mlp" in bp:
         h2 = L.rmsnorm_apply(bp["norm2"], x, cfg.norm_eps)
-        if moe:
-            y, aux = L.moe_apply(bp["mlp"], cfg, h2)
-        else:
-            y = L.mlp_apply(bp["mlp"], cfg, h2)
+        y, aux = _mlp(bp["mlp"], cfg, h2, moe)
         x = x + y
     return x, new_c, aux
+
+
+def _mlp(mp: PyTree, cfg: ModelConfig, h, moe: bool):
+    """(output, moe aux loss) of the block's feed-forward."""
+    with jax.named_scope("mlp"):
+        if moe:
+            return L.moe_apply(mp, cfg, h)
+        return L.mlp_apply(mp, cfg, h), jnp.zeros((), jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -230,10 +236,11 @@ def _block_apply(bp: PyTree, cfg: ModelConfig, kind: str, moe: bool, x,
 
 
 def _embed(params, cfg: ModelConfig, tokens):
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.compute_dtype))
-    if cfg.emb_scale:
-        x = x * float(np.sqrt(cfg.d_model))  # weak-typed: keeps compute dtype
-    return x
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.dtype(cfg.compute_dtype))
+        if cfg.emb_scale:
+            x = x * float(np.sqrt(cfg.d_model))  # weak-typed: keeps dtype
+        return x
 
 
 def encode(params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
@@ -365,6 +372,11 @@ def logits_from_hidden(params, cfg: ModelConfig, h: jax.Array) -> jax.Array:
 
 def cross_entropy_chunked(params, cfg: ModelConfig, h, labels,
                           n_chunks: int = 8) -> jax.Array:
+    with jax.named_scope("lm_head"):
+        return _cross_entropy_chunked(params, cfg, h, labels, n_chunks)
+
+
+def _cross_entropy_chunked(params, cfg, h, labels, n_chunks):
     B, Ltot, D = h.shape
     n_chunks = min(n_chunks, Ltot)
     while Ltot % n_chunks:

@@ -1,17 +1,23 @@
-"""Run-scoped telemetry sink: spans, counters, gauges — zero overhead off.
+"""Run-scoped telemetry sink: spans, counters, gauges — no per-step cost off.
 
 One process-wide *current sink* (module state, :func:`get` / :func:`install`)
 backs every instrumented layer — the train loop, the gossip bus, and the
 simulator driver all emit through it. Two implementations share the API:
 
-* :class:`NullTelemetry` — the default. Every method is a no-op returning a
-  cached null context manager; instrumented code pays one attribute check
-  (``tel.active``) per *amortized* boundary (a ``log_every`` window, a jit
-  trace, a run teardown), never per step. With the null sink installed an
-  instrumented ``train()`` is bit-identical to the untelemetered one — no
-  numerical state is ever touched (``tests/test_telemetry.py`` gates this).
+* :class:`NullTelemetry` — the default. Every emit is a no-op; instrumented
+  code pays one attribute check (``tel.active``) per *amortized* boundary
+  (a ``log_every`` window, a jit trace, a run teardown), never per step.
+  With the null sink installed an instrumented ``train()`` is bit-identical
+  to the untelemetered one — no numerical state is ever touched
+  (``tests/test_telemetry.py`` gates this).
 * :class:`Telemetry` — in-memory event lists (spans / counters / gauges /
   instants) flushed to ``telemetry.json`` with a provenance header.
+
+``span()`` of both sinks also enters a ``jax.profiler.TraceAnnotation``, so a
+profile of the run holds the program's host spans on the profiler's clock,
+beside the device ops (a no-op while no profiler records). Device-side layer
+names are not the sink's business: the train step and the bus put plain
+``jax.named_scope`` boundaries into the compiled program.
 
 Use :func:`run` to scope a sink to a run directory::
 
@@ -32,32 +38,37 @@ import os
 import time
 from typing import Any
 
+import jax
+
 __all__ = ["Telemetry", "NullTelemetry", "NULL", "get", "install",
            "enabled", "run"]
 
 
-class _NullContext:
-    """Reusable no-op context manager (one instance, zero allocation)."""
+class _HostSpan:
+    """A profiler annotation of a host region; records nothing itself."""
 
-    __slots__ = ()
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = jax.profiler.TraceAnnotation(name)
 
     def __enter__(self):
+        self._ann.__enter__()
         return None
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         return False
 
 
-_NULL_CTX = _NullContext()
-
-
 class NullTelemetry:
-    """The disabled sink: every emit is a no-op, ``active`` is False."""
+    """The disabled sink: every emit is a no-op, ``active`` is False; a
+    span is only a profiler annotation."""
 
     active = False
 
     def span(self, name: str, **attrs):
-        return _NULL_CTX
+        return _HostSpan(name)
 
     def complete(self, name: str, ts: float, dur: float, **attrs) -> None:
         pass
@@ -72,10 +83,6 @@ class NullTelemetry:
     def instant(self, name: str, t: float | None = None, **attrs) -> None:
         pass
 
-    def annotate(self, name: str):
-        """Trace-time profiler annotation — a no-op context when disabled."""
-        return _NULL_CTX
-
     def save(self, path: str | None = None) -> None:
         pass
 
@@ -84,12 +91,14 @@ NULL = NullTelemetry()
 
 
 class _Span:
-    __slots__ = ("_tel", "_name", "_attrs", "_t0")
+    __slots__ = ("_tel", "_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: dict):
         self._tel, self._name, self._attrs = tel, name, attrs
+        self._ann = jax.profiler.TraceAnnotation(name)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = self._tel.now()
         return self
 
@@ -97,6 +106,7 @@ class _Span:
         t0 = self._t0
         self._tel.complete(self._name, t0, self._tel.now() - t0,
                            **self._attrs)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -130,7 +140,8 @@ class Telemetry:
     # -- emit -------------------------------------------------------------
 
     def span(self, name: str, **attrs):
-        """Context manager timing a host-side region."""
+        """Context manager timing a host-side region (and annotating it for
+        the profiler)."""
         return _Span(self, name, attrs)
 
     def complete(self, name: str, ts: float, dur: float, **attrs) -> None:
@@ -157,14 +168,6 @@ class Telemetry:
         if attrs:
             rec["attrs"] = attrs
         self.instants.append(rec)
-
-    def annotate(self, name: str):
-        """jax trace-time annotation: a ``jax.named_scope`` so the region
-        shows up named in HLO metadata / ``jax.profiler`` timelines (the
-        hook the fused bus mix wraps its Pallas pass with)."""
-        import jax
-
-        return jax.named_scope(name)
 
     # -- persistence ------------------------------------------------------
 

@@ -120,23 +120,28 @@ def train(
     pending: list[StepMetrics] = []
     t_win = time.perf_counter()
     # Telemetry rides the existing amortized boundaries: one emit batch per
-    # log window (inside flush), nothing per step. With the null sink the
-    # only cost is this truthiness check — the numerics are untouched either
-    # way, so instrumented-but-disabled train() bit-matches plain train().
+    # log window (inside flush), nothing per step. The two host spans,
+    # ``train.dispatch`` (the window's step dispatches) and
+    # ``train.host_sync`` (its one fetch), reach a profile whatever the sink;
+    # the numerics are untouched either way, so instrumented-but-disabled
+    # train() bit-matches plain train().
     tel = telemetry.get()
+    dispatch = None       # the open train.dispatch span of this log window
 
     def flush() -> None:
-        nonlocal t_win
+        nonlocal t_win, dispatch
+        if dispatch is not None:
+            dispatch.__exit__(None, None, None)
+            dispatch = None
         n = len(pending)
-        if tel.active and n:
+        if n:
             with tel.span("train.host_sync", steps=n):
                 hist.extend_from_device(pending, t_win)
+        if tel.active and n:
             dur = time.perf_counter() - t_win
             tel.complete("train.window", tel.now() - dur, dur, steps=n)
             tel.counter("train.steps", n)
             tel.gauge("train.loss", hist.loss[-1])
-        else:
-            hist.extend_from_device(pending, t_win)
         pending.clear()
         t_win = time.perf_counter()
 
@@ -150,6 +155,9 @@ def train(
         with ctx:
             for k in range(steps):
                 batch = next(it)
+                if dispatch is None:
+                    dispatch = tel.span("train.dispatch")
+                    dispatch.__enter__()
                 state, metrics = step_fn(state, batch)
                 pending.append(metrics)
                 if k % log_every == 0 or k == steps - 1:
@@ -171,6 +179,8 @@ def train(
     except BaseException:
         # the loop is already failing: drain the writer but don't let a
         # secondary checkpoint-write error mask the real exception
+        if dispatch is not None:
+            dispatch.__exit__(None, None, None)
         if writer is not None:
             try:
                 writer.close()

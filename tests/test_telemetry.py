@@ -198,6 +198,31 @@ def test_disabled_telemetry_train_bit_match():
     assert telemetry.get() is telemetry.NULL  # context restored the null sink
 
 
+def test_train_host_spans_reach_the_profile_without_a_sink(tmp_path):
+    """train() opens train.dispatch and train.host_sync once per log window
+    as profiler annotations, with the null sink installed."""
+    import glob
+
+    import jax
+
+    X, y, params0, loss = _linear_problem()
+    M = 4
+    spec = GossipSpec(topology=T.undirected_ring(M), backend="einsum")
+    p0 = replicate_for_workers(params0, M)
+    assert telemetry.get() is telemetry.NULL
+    with jax.profiler.trace(str(tmp_path)):
+        train(loss, p0, sgd(0.05), _batches(X, y, M), steps=9, gossip=spec,
+              log_every=4, verbose=False)
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = [e.name for plane in pd.planes if plane.name.startswith("/host")
+             for ln in plane.lines for e in ln.events]
+    # log windows: step 0, steps 1-4, 5-8 (the last flush finds none left)
+    assert names.count("train.dispatch") == 3
+    assert names.count("train.host_sync") == 3
+
+
 def test_health_gauges_do_not_perturb_trace_signature():
     """health=True adds gauges but leaves the event schedule, the signature,
     and the trained parameters bit-identical."""
@@ -398,8 +423,8 @@ def test_null_sink_is_inert_and_reusable():
     assert tel.active is False
     with tel.span("x") as s:
         assert s is None
-    with tel.annotate("y"):
-        pass
+    with tel.span("x", steps=1) as s:   # a fresh profiler annotation each time
+        assert s is None
     tel.counter("c")
     tel.gauge("g", 1.0)
     tel.save()
