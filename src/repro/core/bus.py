@@ -8,16 +8,16 @@ per-iteration exchange is bandwidth-bound; see EXPERIMENTS.md §Perf).
 
 The bus instead:
 
-1. flattens the whole parameter pytree (and, in the fused train step, the
-   optimizer-update pytree) into one contiguous row-major buffer per dtype
-   group, with a cached two-pass layout plan (`BusLayout`, "layout v2"):
+1. lays the whole parameter pytree (and, in the fused train step, the
+   optimizer-update pytree) out as one buffer of 128-lane rows per dtype
+   group, each leaf row-major in a slot of its own, with a cached two-pass
+   layout plan (`BusLayout`, "layout v2"):
 
-   * **pass 1 — row planning**: each dtype group's rows are planned in whole
-     sublane tiles *per model shard* — ``rows % (sublane(dtype) · k) == 0``
-     for shard factor k (8/16/32 sublanes for 4/2/1-byte dtypes) — with the
-     remainder packed into one lane-padded tail chunk (rows are one 128-lane
-     tile wide, so padding is bounded by a single sublane tile per shard,
-     not a full 32-row block);
+   * **pass 1 — row planning**: every leaf's slot is planned in whole
+     sublane tiles of one-lane-tile-wide rows *per model shard* (8/16/32
+     sublanes for 4/2/1-byte dtypes), so each slot starts on a tile
+     boundary, the group satisfies ``rows % (sublane(dtype) · k) == 0`` for
+     shard factor k, and padding stays under one sublane tile per slot;
    * **pass 2 — leaf assignment**: *every* leaf is assigned a row range of
      the flat buffer and split over the model axis **by buffer rows** — the
      bus never needed tensor structure. Leaves whose logical axes shard over
@@ -83,8 +83,8 @@ __all__ = ["BusLayout", "plan_layout", "pack", "unpack", "mix_bus",
            "WIRE_DTYPES", "LANE"]
 
 # Bus rows are exactly one lane tile wide: padding granularity is one
-# sublane tile (sublane(dtype) × 128 elements) per model shard instead of a
-# full 32×block_c block — the lane-padded tail chunk of layout v2.
+# sublane tile (sublane(dtype) × 128 elements) per slot and model shard
+# instead of a full 32×block_c block.
 LANE = 128
 
 
@@ -162,8 +162,9 @@ class _LeafSlot:
 
     leaf_id: int      # index into the flattened pytree
     size: int         # element count of the leaf as seen locally
-    chunk: int        # per-model-shard element count in the buffer
-    offset: int       # start offset in the per-shard flat payload
+    chunk: int        # per-model-shard element count the slot carries
+    offset: int       # first row of the slot in the per-shard buffer
+    rows: int         # per-shard rows: whole sublane tiles holding ``chunk``
     sharded: bool     # True → local value is already the 1/k tensor shard
 
 
@@ -172,13 +173,13 @@ class _Group:
     """Leaves of one dtype packed into one (lead..., R, C) buffer."""
 
     dtype: jnp.dtype
-    slots: tuple[_LeafSlot, ...]   # payload order (row-split first)
+    slots: tuple[_LeafSlot, ...]   # buffer order (row-split first)
     n: int                         # per-shard payload elements (un-padded)
     rows: int                      # R per shard — multiple of sublane(dtype)
     cols: int                      # C — one lane tile (LANE)
     block_r: int                   # tile rows actually used by the kernel
-    split_off: int                 # payload offset where row-split slots begin
-    split_end: int = 0             # payload offset where row-split slots end
+    split_off: int                 # first row of the row-split slots
+    split_end: int = 0             # row where the row-split slots end
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,19 +275,20 @@ def plan_layout(tree: PyTree, *, lead_ndim: int = 1,
     """Build (or fetch from cache) the layout-v2 bus plan for ``tree``.
 
     ``lead_ndim`` leading dims of every leaf (the worker dim in gossip mode)
-    are kept out of the flat row; the remaining trailing elements are laid
-    out contiguously, grouped by dtype, in two passes:
+    are kept out of the buffer rows; each leaf's trailing elements fill a
+    slot of rows, row-major, grouped by dtype, in two passes:
 
-    * pass 1 plans each dtype group's rows as whole sublane tiles per model
-      shard — per-shard ``rows % sublane(dtype) == 0``, so the global buffer
-      satisfies ``rows % (sublane·shards) == 0`` — with the remainder in one
-      lane-padded tail chunk (rows are one LANE tile wide);
-    * pass 2 assigns every leaf an (offset, chunk) row range of the flat
-      payload, splitting it over the model axis by buffer rows.
+    * pass 1 plans every slot as whole sublane tiles of LANE-wide rows, so
+      each slot starts on a tile boundary and the group's per-shard
+      ``rows % sublane(dtype) == 0`` — the global buffer satisfies
+      ``rows % (sublane·shards) == 0`` — with padding under one sublane
+      tile per slot;
+    * pass 2 assigns every leaf an (offset, rows) row range of the buffer,
+      splitting it over the model axis by buffer rows.
       ``leaf_sharded[i]`` (flatten order) marks leaves whose *local* value is
       already the 1/k tensor shard; all other leaves are row-split —
-      shard s owns elements ``[s·chunk, (s+1)·chunk)`` of the flat leaf
-      (``chunk = ⌈n/shards⌉``, last shard zero-padded).
+      shard s owns elements ``[s·rows·LANE, (s+1)·rows·LANE)`` of the flat
+      leaf (``rows`` holding ``⌈n/shards⌉`` elements, zero-padded).
 
     Layout v2 fixes the row width to one lane tile (``LANE``) so tail
     padding is minimal; kernel tile width is a mix-time knob (``block_c`` on
@@ -319,22 +321,22 @@ def plan_layout(tree: PyTree, *, lead_ndim: int = 1,
         # chunks and overlaps the later chunks' fused VMEM passes in the
         # nchunks pipeline (`_mix_group_chunked`).
         ids = sorted(ids, key=lambda i: (flags[i],))
-        slots, off, split_lo, split_hi = [], 0, None, None
+        slots, off, n, split_lo, split_hi = [], 0, 0, None, None
         for i in ids:
             size = int(np.prod(shapes[i], dtype=np.int64))
             whole = flags[i] or size == 0   # nothing to row-split in 0 elems
             chunk = size if whole else -(-size // shards)
+            # pass 1 (row planning): every slot is whole sublane tiles, so
+            # it starts on a tile boundary — padding < sub·LANE per slot
+            slot_rows = -(-chunk // (sub * LANE)) * sub
             if not whole:
                 split_lo = off if split_lo is None else split_lo
-                split_hi = off + chunk
+                split_hi = off + slot_rows
             slots.append(_LeafSlot(leaf_id=i, size=size, chunk=chunk,
-                                   offset=off, sharded=whole))
-            off += chunk
-        n = off
-        # pass 1 (row planning): whole sublane tiles per shard, remainder in
-        # a lane-padded tail — per-shard padding < sub·LANE elements.
-        rows = -(-max(n, 1) // LANE)
-        rows = -(-rows // sub) * sub
+                                   offset=off, rows=slot_rows, sharded=whole))
+            off += slot_rows
+            n += chunk
+        rows = max(off, sub)   # a group of empty leaves still has one tile
         groups.append(_Group(dtype=dt, slots=tuple(slots), n=n, rows=rows,
                              cols=LANE,
                              block_r=_pick_block_r(rows, block_r, sub),
@@ -346,78 +348,106 @@ def plan_layout(tree: PyTree, *, lead_ndim: int = 1,
     return layout
 
 
-def _tile_cut(shape: tuple[int, ...], dtype) -> int:
-    """Rows of a matrix leaf that fill whole sublane tiles, else 0.
+def _slot_view(shape: tuple[int, ...]) -> tuple[tuple[int, ...], bool, int]:
+    """How a leaf of trailing ``shape`` is viewed in its slot, from its shape.
 
-    A matrix whose row count is not a whole number of sublane tiles (the
-    49155-row granite vocab table) sits in tiled HBM with a ragged last
-    tile. Reshaping it to or from a flat row in one piece took the TPU
-    compiler about a minute for that one leaf, so the bus moves its whole
-    tiles and its ragged tail as two pieces.
+    Returns ``(view, swap, grow)``: the slot holds ``view`` row-major, then
+    zeros. Where the leaf's minor dim is not whole 128-lane tiles but the
+    one before it is (mamba2's in_proj, 10576 × 2560 per layer), the TPU
+    keeps the leaf with those two dims swapped, so the view swaps them too
+    and the leaf enters and leaves the bus without a transposing copy
+    (``swap``). Where a matrix's row count is not whole groups of 8
+    sublanes (the 49155-row granite vocab table), the TPU puts the worker
+    dim inside its tiles, and relaying it out to or from bus rows in one
+    piece takes the compiler over a minute and a half for that one leaf;
+    the view pads its rows to whole groups (``grow`` rows of zeros after
+    the leaf, so the slot still starts with the leaf row-major).
     """
-    sub = sublane_rows(dtype)
-    if len(shape) != 2 or shape[0] < sub or shape[0] % sub == 0:
-        return 0
-    return shape[0] - shape[0] % sub
+    swap = len(shape) >= 2 and shape[-1] % LANE != 0 and shape[-2] % LANE == 0
+    if swap:
+        shape = shape[:-2] + (shape[-1], shape[-2])
+    grow = 0
+    if len(shape) == 2 and shape[0] % 8 and shape[1] % 16 == 0:
+        grow = -shape[0] % 8
+        shape = (shape[0] + grow, shape[1])
+    return shape, swap, grow
 
 
-def _flatten(x: jax.Array, lead_ndim: int) -> jax.Array:
-    """``x.reshape(lead + (-1,))``, ragged matrices in two pieces."""
+def _fit_rows(x: jax.Array, rows: int) -> jax.Array:
+    """``x`` (lead..., r, LANE) cut or zero-padded to ``rows`` rows."""
+    have = x.shape[-2]
+    if have > rows:
+        return jax.lax.slice_in_dim(x, 0, rows, axis=x.ndim - 2)
+    if have < rows:
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, rows - have), (0, 0)])
+    return x
+
+
+def _to_rows(x: jax.Array, lead_ndim: int, rows: int) -> jax.Array:
+    """``x``'s trailing elements in their slot view, as ``lead + (rows, LANE)``.
+
+    Zero-padded to ``rows``. A leaf of whole 128-lane rows reshapes straight
+    into its rows, so the TPU compiler writes it to the bus in one tiled
+    copy; only a leaf with a ragged last row (a few hundred elements of
+    per-head scalars) goes through a flat row on the way.
+    """
     lead = x.shape[:lead_ndim]
-    cut = _tile_cut(x.shape[lead_ndim:], x.dtype)
-    if not cut:
-        return jnp.reshape(x, lead + (-1,))
-    head = jax.lax.slice_in_dim(x, 0, cut, axis=lead_ndim)
-    tail = jax.lax.slice_in_dim(x, cut, x.shape[lead_ndim], axis=lead_ndim)
-    return jnp.concatenate([head.reshape(lead + (-1,)),
-                            tail.reshape(lead + (-1,))], axis=-1)
+    view, swap, grow = _slot_view(x.shape[lead_ndim:])
+    if swap:
+        x = jnp.swapaxes(x, -1, -2)
+    if grow:
+        x = jnp.pad(x, [(0, 0)] * lead_ndim + [(0, grow), (0, 0)])
+    n = int(np.prod(view, dtype=np.int64))
+    if n % LANE:
+        x = jnp.pad(x.reshape(lead + (n,)),
+                    [(0, 0)] * lead_ndim + [(0, -n % LANE)])
+    return _fit_rows(x.reshape(lead + (-(-n // LANE), LANE)), rows)
 
 
-def _unflatten(flat: jax.Array, shape: tuple[int, ...]) -> jax.Array:
-    """Inverse of :func:`_flatten` for trailing ``shape``."""
-    lead = flat.shape[:-1]
-    cut = _tile_cut(shape, flat.dtype)
-    if not cut:
-        return flat.reshape(lead + shape)
-    n = cut * shape[1]
-    head = jax.lax.slice_in_dim(flat, 0, n, axis=-1)
-    tail = jax.lax.slice_in_dim(flat, n, flat.shape[-1], axis=-1)
-    return jnp.concatenate(
-        [head.reshape(lead + (cut, shape[1])),
-         tail.reshape(lead + (shape[0] - cut, shape[1]))], axis=len(lead))
+def _from_rows(piece: jax.Array, shape: tuple[int, ...]) -> jax.Array:
+    """Inverse of :func:`_to_rows`: ``lead + (rows, LANE)`` → ``lead + shape``."""
+    lead = piece.shape[:-2]
+    view, swap, grow = _slot_view(shape)
+    n = int(np.prod(view, dtype=np.int64))
+    piece = _fit_rows(piece, -(-n // LANE))
+    if n % LANE:
+        piece = jax.lax.slice_in_dim(piece.reshape(lead + (-1,)), 0, n,
+                                     axis=len(lead))
+    x = piece.reshape(lead + view)
+    if grow:
+        x = jax.lax.slice_in_dim(x, 0, view[0] - grow, axis=len(lead))
+    return jnp.swapaxes(x, -1, -2) if swap else x
 
 
 def pack(tree: PyTree, layout: BusLayout, *, lead_ndim: int = 1,
          shard_index: Any = 0) -> list[jax.Array]:
-    """Flatten ``tree`` into one (lead..., R, C) buffer per dtype group.
+    """Lay ``tree`` out as one (lead..., R, C) buffer per dtype group.
 
-    With ``layout.shards > 1``, ``shard_index`` (python int or traced
-    ``lax.axis_index``) selects which row range of each row-split leaf this
-    shard packs; tensor-sharded leaves pack their local value whole.
+    Each leaf becomes its slot's rows directly and the slots are joined
+    along the row axis, so no ``lead + (elements,)`` row of the whole group
+    is ever built. With ``layout.shards > 1``, ``shard_index`` (python int
+    or traced ``lax.axis_index``) selects which row range of each row-split
+    leaf this shard packs; tensor-sharded leaves pack their local value
+    whole.
     """
     leaves = layout.treedef.flatten_up_to(tree)
     bufs = []
     for g in layout.groups:
         parts = []
         for slot in g.slots:
-            flat = _flatten(leaves[slot.leaf_id], lead_ndim)
-            if not slot.sharded and layout.shards > 1:
-                pad = layout.shards * slot.chunk - slot.size
-                if pad:
-                    flat = jnp.pad(flat, [(0, 0)] * lead_ndim + [(0, pad)])
-                flat = jax.lax.dynamic_slice_in_dim(
-                    flat, shard_index * slot.chunk, slot.chunk, axis=lead_ndim)
-            parts.append(flat)
-        if parts:
-            flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts, -1)
-        else:  # pragma: no cover - group of zero leaves cannot arise
-            flat = jnp.zeros(
-                tuple(1 for _ in range(lead_ndim)) + (0,), g.dtype)
-        pad = g.rows * g.cols - g.n
-        if pad:
-            width = [(0, 0)] * lead_ndim + [(0, pad)]
-            flat = jnp.pad(flat, width)
-        bufs.append(flat.reshape(flat.shape[:lead_ndim] + (g.rows, g.cols)))
+            x = leaves[slot.leaf_id]
+            if slot.sharded or layout.shards == 1:
+                parts.append(_to_rows(x, lead_ndim, slot.rows))
+            else:
+                full = _to_rows(x, lead_ndim, layout.shards * slot.rows)
+                parts.append(jax.lax.dynamic_slice_in_dim(
+                    full, shard_index * slot.rows, slot.rows, axis=lead_ndim))
+        used = sum(slot.rows for slot in g.slots)
+        if used < g.rows:   # a group of empty leaves: one tile of zeros
+            lead = leaves[g.slots[0].leaf_id].shape[:lead_ndim]
+            parts.append(jnp.zeros(lead + (g.rows - used, g.cols), g.dtype))
+        bufs.append(parts[0] if len(parts) == 1 else
+                    jnp.concatenate(parts, axis=lead_ndim))
     # Fence the packed buffers (and, in unpack, the mixed ones) off from
     # their producers and consumers: fused with the leaf reshapes, a
     # granite-width bus took the TPU compiler over a minute and 12 GB of
@@ -431,35 +461,31 @@ def unpack(bufs: Sequence[jax.Array], layout: BusLayout, *,
     """Inverse of :func:`pack` (padding is dropped).
 
     With ``layout.shards > 1``, row-split leaves need the other shards'
-    chunks back: ``gather`` maps the 1-D row-split span of this shard's
-    payload to a ``(shards, span)`` array stacked in shard order (in the
-    distributed path: ``lax.all_gather`` over the model axis — intra-worker
-    ICI, never the inter-worker gossip links).
+    rows back: ``gather`` maps this shard's row-split rows
+    ``buf[split_off:split_end]`` to a ``(shards, span, LANE)`` array stacked
+    in shard order (in the distributed path: ``lax.all_gather`` over the
+    model axis — intra-worker ICI, never the inter-worker gossip links).
     """
     leaves: list[jax.Array | None] = [None] * len(layout.shapes)
     bufs = jax.lax.optimization_barrier(list(bufs))   # see pack
     for g, buf in zip(layout.groups, bufs):
-        lead = buf.shape[:lead_ndim]
-        flat = buf.reshape(lead + (-1,))
         gathered = None
         if layout.shards > 1 and g.split_off < g.split_end:
             assert gather is not None, "row-split leaves need a gather fn"
             assert lead_ndim == 0, "row-split unpack is per-shard (lead_ndim=0)"
-            span = jax.lax.slice_in_dim(flat, g.split_off, g.split_end, axis=0)
-            gathered = gather(span)            # (shards, split span)
+            gathered = gather(jax.lax.slice_in_dim(buf, g.split_off,
+                                                   g.split_end, axis=0))
         for slot in g.slots:
             if slot.sharded or layout.shards == 1:
                 piece = jax.lax.slice_in_dim(
-                    flat, slot.offset, slot.offset + slot.chunk, axis=lead_ndim)
-                leaves[slot.leaf_id] = _unflatten(
-                    piece, layout.shapes[slot.leaf_id])
+                    buf, slot.offset, slot.offset + slot.rows, axis=lead_ndim)
             else:
                 off = slot.offset - g.split_off
                 piece = jax.lax.slice_in_dim(
-                    gathered, off, off + slot.chunk, axis=1)
-                piece = piece.reshape(-1)[:slot.size]
-                leaves[slot.leaf_id] = _unflatten(
-                    piece, layout.shapes[slot.leaf_id])
+                    gathered, off, off + slot.rows, axis=1
+                ).reshape(layout.shards * slot.rows, g.cols)
+            leaves[slot.leaf_id] = _from_rows(piece,
+                                              layout.shapes[slot.leaf_id])
     return layout.treedef.unflatten(leaves)
 
 
@@ -522,14 +548,14 @@ def _mix_group_chunked(x2, u2, rows, block_r, block_c, weights, eta, pairs,
 
     ``gather``/``span``: the model-sharded path's post-mix re-assembly of
     row-split leaves folds into the same pipeline. ``span`` is the
-    (start, end) element range of the row-split payload — a HEAD span since
-    layout v2 packs row-split leaves first — and ``gather`` maps it to the
-    (shards, span) stack (one ``all_gather`` over the model axis). The
-    gather is issued as soon as the chunks covering the span have run, so
-    its operand depends only on the EARLY chunks: the intra-worker ICI
-    gather overlaps the remaining chunks' fused VMEM passes instead of
-    waiting for the whole buffer. Returns (mixed, gathered) when a gather is
-    requested, else just the mixed buffer.
+    (start, end) row range of the row-split slots — a HEAD span since
+    layout v2 packs row-split leaves first — and ``gather`` maps those rows
+    to the (shards, span, cols) stack (one ``all_gather`` over the model
+    axis). The gather is issued as soon as the chunks covering the span
+    have run, so its operand depends only on the EARLY chunks: the
+    intra-worker ICI gather overlaps the remaining chunks' fused VMEM passes
+    instead of waiting for the whole buffer. Returns (mixed, gathered) when
+    a gather is requested, else just the mixed buffer.
     """
     chunks = _chunk_starts(rows, min(block_r, rows), nchunks)
 
@@ -538,13 +564,8 @@ def _mix_group_chunked(x2, u2, rows, block_r, block_c, weights, eta, pairs,
         x_c = jax.lax.slice_in_dim(x2, start, start + size, axis=0)
         return [jax.lax.ppermute(x_c, axes, pr) for pr in pairs]
 
-    def flat_prefix(pieces):
-        head = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, 0)
-        return head.reshape(-1)
-
     nbrs = permute(0)
-    pieces, gathered, done = [], None, 0
-    cols = x2.shape[-1]
+    pieces, gathered = [], None
     for c, (start, size) in enumerate(chunks):
         nxt = permute(c + 1) if c + 1 < len(chunks) else None
         w_c = jax.lax.slice_in_dim(x2, start, start + size, axis=0)
@@ -554,10 +575,10 @@ def _mix_group_chunked(x2, u2, rows, block_r, block_c, weights, eta, pairs,
             w_c, nbrs, weights, u_c, eta,
             block_r=min(block_r, size), block_c=block_c,
             interpret=interpret, donate=donate))
-        done += size * cols
-        if gather is not None and gathered is None and done >= span[1]:
-            gathered = gather(jax.lax.slice_in_dim(
-                flat_prefix(pieces), span[0], span[1], axis=0))
+        if gather is not None and gathered is None and start + size >= span[1]:
+            head = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, 0)
+            gathered = gather(jax.lax.slice_in_dim(head, span[0], span[1],
+                                                   axis=0))
         nbrs = nxt
     out = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, 0)
     return out if gather is None else (out, gathered)

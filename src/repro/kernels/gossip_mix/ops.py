@@ -6,8 +6,8 @@ Two entry points:
   grid and run through the Pallas kernel (kept for tests / ad-hoc use).
 * :func:`gossip_mix_pytree` — the whole pytree packs ONCE into the flat bus
   layout (`repro.core.bus.BusLayout` — the layout-v2 two-pass plan: cached
-  flatten/unflatten with per-leaf row-range slots, rows in whole sublane
-  tiles with a lane-padded tail) and runs ONE kernel call per dtype group,
+  pack/unpack with per-leaf row-range slots, each slot whole sublane tiles
+  of 128-lane rows) and runs ONE kernel call per dtype group,
   instead of the old per-leaf Python loop of pad/stack/kernel dispatches.
 
 ``interpret=None`` (default) compiles the kernel on TPU and runs it in

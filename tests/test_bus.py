@@ -44,15 +44,49 @@ def _tree(M, seed=0, dtypes=(jnp.float32,)):
 # ---------------------------------------------------------------------------
 
 
+def _chip_tree(M, seed=0, dtypes=(jnp.float32,)):
+    """The chip cells' leaf shapes in small: a stacked lane-ragged minor dim
+    after whole lanes (mamba2 in_proj, 2560 × 10576, 10576 = 80 + 128·82), a
+    row count that is not whole sublane tiles (the 49155-row vocab table),
+    heads under one lane (granite's 32 × 64), per-head scalars and a 1-D
+    scale."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    dt = dtypes[-1]
+    return {
+        "in_proj": jax.random.normal(ks[0], (M, 2, 128, 80 + 128)).astype(dt),
+        "embed": jax.random.normal(ks[1], (M, 35, 256)).astype(dt),
+        "wq": jax.random.normal(ks[2], (M, 2, 24, 4, 64)).astype(dt),
+        "A_log": jax.random.normal(ks[3], (M, 5, 80)).astype(dt),
+        "scale": jax.random.normal(ks[4], (M, 80)),
+    }
+
+
+@pytest.mark.parametrize("make", [_tree, _chip_tree])
 @pytest.mark.parametrize("lead_ndim", [0, 1])
 @pytest.mark.parametrize("dtypes", [(jnp.float32,), (jnp.float32, jnp.bfloat16)])
-def test_pack_unpack_roundtrip(lead_ndim, dtypes):
-    tree = _tree(4, dtypes=dtypes)
+def test_pack_unpack_roundtrip(lead_ndim, dtypes, make):
+    tree = make(4, dtypes=dtypes)
     if lead_ndim == 0:  # strip the worker dim: per-worker view
         tree = jax.tree.map(lambda x: x[0], tree)
     layout = bus.plan_layout(tree, lead_ndim=lead_ndim, **PLAN)
     bufs = bus.pack(tree, layout, lead_ndim=lead_ndim)
     assert len(bufs) == len(set(jnp.dtype(d) for d in dtypes))
+    # every slot starts on a whole sublane tile and holds its leaf
+    # row-major, a lane-ragged minor dim after whole lanes swapped with it
+    leaves = jax.tree.leaves(tree)
+    for g, buf in zip(layout.groups, bufs):
+        sub = bus.sublane_rows(g.dtype)
+        for slot in g.slots:
+            assert slot.offset % sub == 0 and slot.rows % sub == 0
+            leaf = np.asarray(leaves[slot.leaf_id], np.float32)
+            if leaf.ndim - lead_ndim >= 2 and leaf.shape[-1] % bus.LANE and \
+                    not leaf.shape[-2] % bus.LANE:
+                leaf = np.swapaxes(leaf, -1, -2)
+            flat = leaf.reshape(leaf.shape[:lead_ndim] + (-1,))
+            rows = np.asarray(buf[..., slot.offset:slot.offset + slot.rows, :],
+                              np.float32).reshape(flat.shape[:-1] + (-1,))
+            np.testing.assert_array_equal(rows[..., :slot.size], flat)
+            assert not rows[..., slot.size:].any()
     back = bus.unpack(bufs, layout, lead_ndim=lead_ndim)
     for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
         assert a.shape == b.shape and a.dtype == b.dtype
@@ -68,12 +102,15 @@ def test_layout_is_cached_and_padded_to_tiles():
     M = 4  # lead_ndim=1 layout counts per-worker (trailing) elements
     assert l1.payload_elements() == sum(x.size // M for x in jax.tree.leaves(tree))
     for g in l1.groups:
-        # layout v2: whole dtype-native sublane tiles (8 rows for fp32), one
-        # lane-tile-wide rows, remainder lane-padded — not a full 32-row block
+        # layout v2: whole dtype-native sublane tiles (8 rows for fp32) per
+        # slot, one-lane-tile-wide rows — padding under one sublane tile per
+        # slot, not a full 32-row block
         sub = bus.sublane_rows(g.dtype)
         assert g.rows % sub == 0 and g.cols == bus.LANE
         assert g.rows * g.cols >= g.n
-        assert g.rows * g.cols - g.n < sub * bus.LANE
+        assert g.rows * g.cols - g.n < sub * bus.LANE * len(g.slots)
+        for slot in g.slots:
+            assert slot.rows * g.cols - slot.size < sub * bus.LANE
 
 
 def test_pack_padding_is_zero():

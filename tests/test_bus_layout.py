@@ -37,19 +37,69 @@ def _assert_tree_bit_equal(a, b):
         assert np.array_equal(_bits(xa), _bits(xb)), pa
 
 
+def _assert_slots_tiled(layout):
+    """Every slot is whole sublane tiles of LANE-wide rows, starting on a
+    tile boundary right after the previous one: padding under one sublane
+    tile per slot, plus the group's tail (one tile of zeros when every leaf
+    of the group is empty)."""
+    for g in layout.groups:
+        sub = bus.sublane_rows(g.dtype)
+        assert g.cols == bus.LANE and g.rows % sub == 0
+        off = 0
+        for slot in g.slots:
+            assert slot.offset == off and slot.offset % sub == 0
+            assert slot.rows % sub == 0
+            assert slot.chunk <= slot.rows * bus.LANE < slot.chunk + sub * bus.LANE
+            off += slot.rows
+        assert g.n == sum(slot.chunk for slot in g.slots)
+        assert g.rows == (off if off else sub)
+
+
+def _slot_order(leaf, lead_ndim=0):
+    """The leaf as its slot holds it: row-major, except that a lane-ragged
+    minor dim after whole lanes is swapped with it (the TPU's own layout of
+    such a leaf), then flattened past the lead dims."""
+    leaf = np.asarray(leaf)
+    if leaf.ndim - lead_ndim >= 2 and leaf.shape[-1] % bus.LANE and \
+            not leaf.shape[-2] % bus.LANE:
+        leaf = np.swapaxes(leaf, -1, -2)
+    return leaf.reshape(leaf.shape[:lead_ndim] + (-1,))
+
+
+def _assert_slots_hold_leaves(tree, layout, bufs, k, s, lead_ndim=0):
+    """Shard s's rows of each slot hold its share of the leaf in slot order:
+    elements [s·rows·LANE, (s+1)·rows·LANE) of it, zero-padded."""
+    leaves = layout.treedef.flatten_up_to(tree)
+    for g, buf in zip(layout.groups, bufs):
+        buf = np.asarray(buf)
+        lead = buf.shape[:lead_ndim]
+        for slot in g.slots:
+            parts = 1 if slot.sharded else k
+            span = slot.rows * bus.LANE
+            flat = _slot_order(leaves[slot.leaf_id], lead_ndim)
+            want = np.zeros(lead + (parts * span,), flat.dtype)
+            want[..., :flat.shape[-1]] = flat
+            want = want[..., (s % parts) * span:(s % parts + 1) * span]
+            got = buf[..., slot.offset:slot.offset + slot.rows, :]
+            assert np.array_equal(_bits(got.reshape(lead + (-1,))),
+                                  _bits(want)), slot
+
+
 def _roundtrip_row_split(tree, k):
     """Emulate the k model shards host-side: every leaf row-split (the local
     value is the full leaf — the shard_map body's view of replicated leaves),
     each shard packs its row range, unpack gathers the shards back."""
     layout = bus.plan_layout(tree, lead_ndim=0, shards=k, **BLK)
+    _assert_slots_tiled(layout)
     shard_bufs = [bus.pack(tree, layout, lead_ndim=0, shard_index=s)
                   for s in range(k)]
+    for s in range(k):
+        _assert_slots_hold_leaves(tree, layout, shard_bufs[s], k, s)
     spans = {}
     for gi, g in enumerate(layout.groups):
         if k > 1 and g.split_off < g.split_end:
-            spans[gi] = jnp.stack([
-                shard_bufs[s][gi].reshape(-1)[g.split_off:g.split_end]
-                for s in range(k)])
+            spans[gi] = jnp.stack([shard_bufs[s][gi][g.split_off:g.split_end]
+                                   for s in range(k)])
     span_iter = iter([spans[gi] for gi in sorted(spans)])
     return bus.unpack(shard_bufs[0], layout, lead_ndim=0,
                       gather=lambda _span: next(span_iter)), layout
@@ -78,6 +128,15 @@ ADVERSARIAL = [
     [((64, 3), jnp.float32), ((0,), jnp.bfloat16)],
     # scalar-ish leaves only — payload smaller than one sublane tile
     [((1,), jnp.float32), ((2, 1), jnp.float32)],
+    # the chip cells' leaf shapes in small: a stacked lane-ragged minor dim
+    # after whole lanes (mamba2 in_proj, 2560 × 10576, 10576 = 80 + 128·82)
+    # and one after a ragged dim, a row count that is not whole sublane
+    # tiles (the 49155-row vocab table), heads under one lane (granite's
+    # 32 × 64), per-head scalars and 1-D scales, f32 and bf16
+    [((2, 128, 80 + 128), jnp.bfloat16), ((3, 16, 80 + 128), jnp.bfloat16),
+     ((35, 256), jnp.bfloat16),
+     ((2, 24, 4, 64), jnp.bfloat16), ((5, 80), jnp.bfloat16),
+     ((80,), jnp.float32), ((35, 80 + 128), jnp.float32), ((256,), jnp.float32)],
 ]
 
 
@@ -104,7 +163,7 @@ def test_mixed_sharded_and_row_split_leaves(k):
                   for s in range(k)]
     (g,) = layout.groups
     assert g.split_off == 0, "row-split leaves pack at the HEAD of the group"
-    span = jnp.stack([shard_bufs[s][0].reshape(-1)[g.split_off:g.split_end]
+    span = jnp.stack([shard_bufs[s][0][g.split_off:g.split_end]
                       for s in range(k)])
     for s in range(k):
         back = bus.unpack(shard_bufs[s], layout, lead_ndim=0,
@@ -114,17 +173,19 @@ def test_mixed_sharded_and_row_split_leaves(k):
 
 @pytest.mark.parametrize("k", KS)
 def test_pass1_rows_are_whole_tiles_per_shard(k):
-    """Pass-1 invariant: per-shard rows are whole sublane tiles — the global
-    buffer satisfies rows % (sublane(dtype)·k) == 0 because every shard packs
-    the SAME (rows, cols) buffer shape (SPMD uniformity) — and the tail is
-    only lane-padded: per-shard padding < one sublane tile of elements."""
+    """Pass-1 invariant: every slot is whole sublane tiles per shard and
+    starts on a tile boundary, so per-shard rows are whole sublane tiles —
+    the global buffer satisfies rows % (sublane(dtype)·k) == 0 because every
+    shard packs the SAME (rows, cols) buffer shape (SPMD uniformity) — and
+    per-shard padding is under one sublane tile per slot."""
     tree = _rand_tree(ADVERSARIAL[2], seed=11)
     layout = bus.plan_layout(tree, lead_ndim=0, shards=k, **BLK)
+    _assert_slots_tiled(layout)
     for g in layout.groups:
         sub = bus.sublane_rows(g.dtype)
         assert g.cols == bus.LANE
         assert g.rows % sub == 0
-        assert g.rows * g.cols - g.n < sub * bus.LANE  # lane-padded tail only
+        assert g.rows * g.cols - g.n < sub * bus.LANE * len(g.slots)
     # every shard's packed buffers have identical shapes/dtypes (the global
     # buffer is k equal tile-aligned row blocks, one per model shard)
     shapes = {s: [(b.shape, b.dtype) for b in
@@ -236,14 +297,17 @@ def test_property_roundtrip_bit_exact(sizes, dtype_bits, k, seed):
     rows=st.integers(min_value=1, max_value=600),
     tail=st.integers(min_value=0, max_value=127),
     k=st.sampled_from(KS),
+    leaves=st.integers(min_value=1, max_value=4),
 )
-def test_property_pass1_padding_bound(rows, tail, k):
-    tree = {"x": jnp.ones((rows * bus.LANE + tail,), jnp.float32)}
+def test_property_pass1_padding_bound(rows, tail, k, leaves):
+    tree = {f"x{i}": jnp.ones((rows * bus.LANE + tail + i,), jnp.float32)
+            for i in range(leaves)}
     layout = bus.plan_layout(tree, lead_ndim=0, shards=k, **BLK)
+    _assert_slots_tiled(layout)
     (g,) = layout.groups
     sub = bus.sublane_rows(g.dtype)
     assert g.rows % sub == 0
-    assert g.rows * g.cols - g.n < sub * bus.LANE
+    assert g.rows * g.cols - g.n < sub * bus.LANE * len(g.slots)
 
 
 # ---------------------------------------------------------------------------

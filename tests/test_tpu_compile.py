@@ -7,6 +7,7 @@ collectives the compiler put in. The topology is described inside a fixture
 so that importing this file never loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,29 @@ def test_one_chip_bus_compiles_at_granite_width(one_chip):
     hlo = f.lower(p, p).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert " gather(" not in hlo
+
+
+def test_one_chip_bus_packs_leaves_straight_into_rows(one_chip):
+    """4 workers' mamba2 in_proj (minor dim 10576, not whole lanes) and
+    vocab table go into the bus leaf by leaf: no loop splitting a copy, no
+    flat [workers, elements] row of the whole bus, one gossip_mix call."""
+    cfg = get_config("mamba2-2.7b", n_layers=1)
+    tree = abstract_tree(M.model_defs(cfg), jnp.bfloat16)
+    leaves = {"in_proj": tree["segments"][0][0]["mix"]["in_proj"],
+              "embed": tree["embed"]}
+    assert leaves["in_proj"].shape[-1] % bus.LANE
+    p = {k: _spec((WORKERS,) + s.shape, s.dtype, one_chip)
+         for k, s in leaves.items()}
+    spec = GossipSpec(topology=T.undirected_ring(WORKERS), backend="fused")
+    f = jax.jit(lambda q, u: bus.mix_bus(q, spec, None, updates=u, eta=-1.0,
+                                         interpret=False))
+    hlo = f.lower(p, p).compile().as_text()
+    assert " while(" not in hlo
+    flat_rows = [int(n) for n in re.findall(r"bf16\[4,(\d+)\]", hlo)]
+    assert not [n for n in flat_rows if n >= 2**20], flat_rows
+    calls = [ln for ln in hlo.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%gossip_mix" in calls[0], calls
 
 
 @pytest.mark.parametrize("with_specs", [False, True])
