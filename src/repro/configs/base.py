@@ -33,26 +33,34 @@ class ModelConfig:
     emb_scale: bool = False           # gemma: embeddings × sqrt(d_model)
     tie_embeddings: bool = False
 
-    # --- MoE ----------------------------------------------------------------
-    n_experts: int = 0
+    # --- MoE ---------------------------------------------------------------
+    n_experts: int = 0                # experts this chip holds
     n_shared_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0       # deepseek-v2: first layer(s) dense
-    capacity_factor: float = 1.25
+    router_experts: int = 0           # the router's width; 0: n_experts (all
+                                      # held). Above n_experts the chip holds
+                                      # [expert_offset, expert_offset +
+                                      # n_experts) of them (expert parallel)
+    expert_offset: int = 0
+    norm_topk_prob: bool = True       # renormalise the top-k weights
+    moe_aux: str = "global"           # global (Switch) | seq (deepseek-v2)
     router_aux_coef: float = 0.01
-    moe_dispatch: str = "global"      # global | per_sequence (§Perf: keeps the
-                                      # dispatch local to batch shards)
-    moe_shard: str = "auto"           # auto | capacity (§Perf: shard the
-                                      # capacity dim over 'model', replicate
-                                      # expert weights — removes the expanded-
-                                      # buffer TP psum; serving-oriented)
 
     # --- MLA (deepseek-v2) ----------------------------------------------------
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0
     v_head_dim: int = 0
+
+    # --- YaRN rope scaling (factor 1: plain rope) ------------------------------
+    rope_scaling_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM (mamba2 SSD) -----------------------------------------------------
     ssm_state: int = 0
@@ -116,6 +124,10 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim
 
     @property
+    def n_router_experts(self) -> int:
+        return self.router_experts or self.n_experts
+
+    @property
     def moe_layer_flags(self) -> tuple[bool, ...]:
         if not self.n_experts:
             return (False,) * self.n_layers
@@ -135,7 +147,7 @@ class ModelConfig:
         gate = {"swiglu": 3, "geglu": 3, "relu2": 2, "gelu": 2}[self.mlp_type]
         per_mlp = gate * D * F
         per_moe = (self.n_experts + self.n_shared_experts) * gate * D * self.d_ff_expert \
-            + D * self.n_experts if self.n_experts else 0
+            + D * self.n_router_experts if self.n_experts else 0
         per_ssm = (2 * self.d_inner + 2 * self.ssm_ngroups * self.ssm_state + self.ssm_nheads) * D \
             + self.d_inner * D if self.ssm_state else 0
         total = n
@@ -178,6 +190,8 @@ class ModelConfig:
             d_ff=max(self.d_ff // scale, 32),
             vocab_size=min(self.vocab_size, 1024),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            router_experts=min(self.router_experts, 8),
+            expert_offset=0,
             n_shared_experts=min(self.n_shared_experts, 1),
             top_k=min(self.top_k, 2) if self.top_k else 0,
             d_ff_expert=max(self.d_ff_expert // scale, 16) if self.d_ff_expert else 0,

@@ -167,24 +167,18 @@ def build_and_compile(arch: str, shape_name: str, *, multi_pod: bool = False,
                       mode: str | None = None, gossip_period: int = 1,
                       microbatch: int | None = None,
                       worker_internal: str = "tp",
-                      moe_dispatch: str | None = None,
                       shard_activations: str | None = None,
                       parallel_block: bool = False,
-                      moe_shard: str | None = None,
                       save_hlo: str | None = None,
                       donate: bool = True,
                       reduced: bool = False,
                       hierarchical: bool = False) -> DryrunResult:
     cfg = get_config(arch, reduced=True) if reduced else get_config(arch)
     overrides = {}
-    if moe_dispatch:
-        overrides["moe_dispatch"] = moe_dispatch
     if shard_activations:
         overrides["shard_activations"] = shard_activations
     if parallel_block:
         overrides["parallel_block"] = True
-    if moe_shard:
-        overrides["moe_shard"] = moe_shard
     if overrides:
         import dataclasses as _dc
         cfg = _dc.replace(cfg, **overrides)
@@ -349,10 +343,8 @@ def main(argv=None) -> int:
     ap.add_argument("--gossip-period", type=int, default=1)
     ap.add_argument("--microbatch", type=int, default=None)
     ap.add_argument("--worker-internal", default="tp", choices=("tp", "dp", "fsdp"))
-    ap.add_argument("--moe-dispatch", default=None)
     ap.add_argument("--shard-activations", default=None, nargs="?", const="model")
     ap.add_argument("--parallel-block", action="store_true")
-    ap.add_argument("--moe-shard", default=None)
     ap.add_argument("--mode", default=None)
     ap.add_argument("--tag", default="")
     ap.add_argument("--save-hlo", default=None)
@@ -461,10 +453,8 @@ def main(argv=None) -> int:
                   topology=args.topology, gossip_backend=args.gossip_backend,
                   gossip_period=args.gossip_period, microbatch=args.microbatch,
                   worker_internal=args.worker_internal,
-                  moe_dispatch=args.moe_dispatch,
                   shard_activations=args.shard_activations,
                   parallel_block=args.parallel_block,
-                  moe_shard=args.moe_shard,
                   mode=args.mode, save_hlo=args.save_hlo,
                   hierarchical=args.hierarchical)
     path = save_result(res, args.tag)

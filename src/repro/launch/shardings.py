@@ -63,12 +63,7 @@ def param_pspecs(cfg: ModelConfig, mesh, mode: str | None = None,
         # all-reducing activations: FSDP-within-worker (§Perf hillclimb A).
         return tree_specs(defs, mesh=wm.mesh, prefix_axes=(wm.wa,))
     if mode == "allreduce":
-        rules = None
-        if cfg.moe_shard == "capacity":
-            rules = dict(DEFAULT_RULES)
-            rules["experts"] = None
-            rules["expert_ff"] = None   # replicate expert weights
-        return tree_specs(defs, rules=rules, mesh=wm.mesh)
+        return tree_specs(defs, mesh=wm.mesh)
     if mode == "fsdp":
         rules = dict(DEFAULT_RULES)
         rules["embed"] = wm.wa              # shard d_model over worker axes

@@ -15,6 +15,7 @@ KV caches:
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -22,11 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import rmsnorm_apply, rmsnorm_defs, rope
+from repro.models.layers import (rmsnorm_apply, rmsnorm_defs, rope,
+                                 rope_inv_freq, yarn_mscale)
 from repro.models.params import ParamDef
 
 PyTree = Any
 NEG_INF = -1e30
+# float32 score blocks a blockwise attention's backward may keep, in all
+REMAT_SCORES_BYTES = 1 << 30
 
 
 # ---------------------------------------------------------------------------
@@ -82,7 +86,10 @@ def blockwise_attention(q, k, v, q_base: int, *, causal=True, window=None,
 
     Python-unrolled over q blocks; each q block scans only the kv blocks its
     mask can reach (causal / sliding window), so FLOPs match the masked
-    dense computation (roofline honesty).
+    dense computation (roofline honesty). Where the float32 score blocks a
+    backward pass would keep exceed ``REMAT_SCORES_BYTES`` in all, each kv
+    step is recomputed in the backward instead (only the running max, sum
+    and accumulator are kept).
     """
     B, Lq, H, hd = q.shape
     Lkv = k.shape[1]
@@ -94,16 +101,23 @@ def blockwise_attention(q, k, v, q_base: int, *, causal=True, window=None,
     nkv = max(Lkv // kv_chunk, 1)
     kv_chunk = Lkv // nkv
 
-    outs = []
-    for qb in range(nq):
-        q_pos = q_base + qb * q_chunk + jnp.arange(q_chunk)
-        qg = jax.lax.dynamic_slice_in_dim(q, qb * q_chunk, q_chunk, 1)
-        # static kv block range reachable under the mask
+    def reach(qb):
+        """The static kv block range q block ``qb``'s mask can reach."""
         hi = nkv if not causal else min(
             (q_base + (qb + 1) * q_chunk - 1) // kv_chunk + 1, nkv)
         lo = 0
         if window is not None:
             lo = max((q_base + qb * q_chunk - window + 1) // kv_chunk, 0)
+        return lo, hi
+
+    steps = sum(hi - lo for lo, hi in map(reach, range(nq)))
+    remat = B * H * q_chunk * kv_chunk * 4 * steps > REMAT_SCORES_BYTES
+
+    outs = []
+    for qb in range(nq):
+        q_pos = q_base + qb * q_chunk + jnp.arange(q_chunk)
+        qg = jax.lax.dynamic_slice_in_dim(q, qb * q_chunk, q_chunk, 1)
+        lo, hi = reach(qb)
         m = jnp.full((B, H, q_chunk), NEG_INF, jnp.float32)
         l = jnp.zeros((B, H, q_chunk), jnp.float32)
         acc = jnp.zeros((B, H, q_chunk, v.shape[-1]), jnp.float32)
@@ -125,7 +139,8 @@ def blockwise_attention(q, k, v, q_base: int, *, causal=True, window=None,
             return (m_new, l_new, acc_new), None
 
         (m, l, acc), _ = jax.lax.scan(
-            body, (m, l, acc), jnp.arange(lo, hi), length=hi - lo)
+            jax.checkpoint(body) if remat else body, (m, l, acc),
+            jnp.arange(lo, hi), length=hi - lo)
         o = acc / jnp.maximum(l, 1e-30)[..., None]
         outs.append(o.astype(q.dtype))
     out = jnp.concatenate(outs, axis=2) if nq > 1 else outs[0]
@@ -548,12 +563,33 @@ def _mla_paged_attention(params, q_nope, q_rope, ckv_pages, kr_pages,
     return jnp.einsum("shk,hkd->sd", o, params["wo"])[:, None]
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-1/2, times mscale(yarn_mscale_all_dim)^2 under
+    YaRN (DeepSeek-V2: 192^-1/2 * 1.260804^2 = 0.1147214)."""
+    scale = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.rope_scaling_factor > 1 and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_scaling_factor,
+                             cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def mla_apply(params, cfg: ModelConfig, x, *, q_base: int = 0,
               cache=None, lengths=None, prompt_len: int | None = None):
+    """DeepSeek-V2 multi-head latent attention (no q-LoRA).
+
+    q = x W_q (H, nope + rope) split into q_nope, q_pe; x W_dkv gives
+    r + rope: c = RMSNorm(first r), k_pe the last rope dims, one for all
+    heads; k_nope = c W_uk, v = c W_uv; RoPE (YaRN frequencies where the
+    config scales them, :func:`rope_inv_freq`) on q_pe and k_pe; softmax
+    at :func:`mla_softmax_scale`; out o W_o. Training and prefill use this
+    expanded form, cached decode the absorbed one in compressed space.
+    """
     B, L, D = x.shape
     H = cfg.n_heads
     r, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    scale = 1.0 / np.sqrt(dn + dr)
+    scale = mla_softmax_scale(cfg)
+    pe = functools.partial(rope, theta=cfg.rope_theta,
+                           inv_freq=rope_inv_freq(cfg, dr))
 
     q = jnp.einsum("bld,dhk->blhk", x, params["wq"])           # (B,L,H,dn+dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
@@ -565,8 +601,8 @@ def mla_apply(params, cfg: ModelConfig, x, *, q_base: int = 0,
         # paged decode — absorbed form over the slot's block-table context
         assert L == 1, "paged MLA cache is decode-only (prefill scatters in)"
         qp = cache.lengths[:, None]                              # (S, 1)
-        q_rope = rope(q_rope, qp, cfg.rope_theta)
-        k_rope_new = rope(k_rope_in, qp, cfg.rope_theta)[:, :, 0]
+        q_rope = pe(q_rope, qp)
+        k_rope_new = pe(k_rope_in, qp)[:, :, 0]
         cp = _paged_write(cache.ckv_pages, cache.block_tables, cache.lengths, ckv)
         kp = _paged_write(cache.kr_pages, cache.block_tables, cache.lengths,
                           k_rope_new)
@@ -578,8 +614,8 @@ def mla_apply(params, cfg: ModelConfig, x, *, q_base: int = 0,
     if cache is None or L > 1:
         # training forward, or prefill (cache assumed empty): expanded form
         q_pos = q_base + jnp.arange(L)
-        q_rope = rope(q_rope, q_pos, cfg.rope_theta)
-        k_rope = rope(k_rope_in, q_pos, cfg.rope_theta)[:, :, 0]  # (B,L,dr)
+        q_rope = pe(q_rope, q_pos)
+        k_rope = pe(k_rope_in, q_pos)[:, :, 0]  # (B,L,dr)
         k_nope = jnp.einsum("blr,rhk->blhk", ckv, params["w_uk"])
         v = jnp.einsum("blr,rhk->blhk", ckv, params["w_uv"])
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
@@ -603,8 +639,8 @@ def mla_apply(params, cfg: ModelConfig, x, *, q_base: int = 0,
         qp = (cache.pos - (prompt_len - lengths))[:, None]       # (B, 1)
     else:
         qp = cache.pos + jnp.arange(L)
-    q_rope = rope(q_rope, qp, cfg.rope_theta)
-    k_rope_new = rope(k_rope_in, qp, cfg.rope_theta)[:, :, 0]
+    q_rope = pe(q_rope, qp)
+    k_rope_new = pe(k_rope_in, qp)[:, :, 0]
     ckv_all = jax.lax.dynamic_update_slice_in_dim(cache.ckv, ckv, cache.pos, 1)
     kr_all = jax.lax.dynamic_update_slice_in_dim(cache.krope, k_rope_new, cache.pos, 1)
     new_cache = MLACache(ckv_all, kr_all, cache.pos + L)

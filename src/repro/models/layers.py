@@ -1,10 +1,12 @@
-"""Shared layers: norms, rotary embeddings, MLP variants, Mixture-of-Experts.
+"""Shared layers: norms, rotary embeddings (plain and YaRN), MLP variants,
+Mixture-of-Experts (dropless, over the experts a chip holds).
 
 All modules expose ``<name>_defs(cfg, ...)`` returning a ParamDef pytree and
 ``<name>_apply(params, cfg, x, ...)``.
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -38,16 +40,60 @@ def rmsnorm_apply(params: PyTree, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """Apply rotary embedding.  x: (..., L, H, hd); positions: (..., L)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         inv_freq: np.ndarray | None = None) -> jax.Array:
+    """Apply rotary embedding.  x: (..., L, H, hd); positions: (..., L).
+
+    ``inv_freq`` (hd / 2,) replaces the plain frequencies theta^(-2i/hd)
+    (YaRN: :func:`rope_inv_freq`)."""
     hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
+    else:
+        freqs = np.asarray(inv_freq, np.float32)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., L, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray | None:
+    """YaRN frequencies of a ``dim``-wide rotary part (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``), or None for plain rope:
+
+        f_extra,i = theta^(-2i/dim),  f_inter,i = f_extra,i / factor,
+        d(r) = dim ln(L_orig / (2 pi r)) / (2 ln theta),
+        low = floor(d(beta_fast)),  high = ceil(d(beta_slow)),
+        ramp_i = clip((i - low) / (high - low), 0, 1),
+        inv_freq_i = f_inter,i ramp_i + f_extra,i (1 - ramp_i).
+
+    cos and sin carry mscale(yarn_mscale) / mscale(yarn_mscale_all_dim),
+    which is 1 where the two are equal (DeepSeek-V2), the only case taken.
+    """
+    factor = cfg.rope_scaling_factor
+    if factor <= 1:
+        return None
+    if cfg.yarn_mscale != cfg.yarn_mscale_all_dim:
+        raise NotImplementedError("YaRN with mscale != mscale_all_dim")
+    base = cfg.rope_theta
+    f_extra = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def d(r):
+        return (dim * math.log(cfg.rope_original_max_pos / (2 * math.pi * r))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(d(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(d(cfg.yarn_beta_slow)), dim - 1)
+    if high == low:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return f_extra / factor * ramp + f_extra * (1.0 - ramp)
 
 
 # ---------------------------------------------------------------------------
@@ -87,7 +133,7 @@ def mlp_apply(params: PyTree, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts (capacity-based scatter dispatch, shared + routed)
+# Mixture of Experts: dropless, over the experts this chip holds
 # ---------------------------------------------------------------------------
 
 
@@ -95,7 +141,8 @@ def moe_defs(cfg: ModelConfig) -> PyTree:
     D, E, Fe = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
     gate_mats = 3 if cfg.mlp_type in ("swiglu", "geglu") else 2
     defs: PyTree = {
-        "router": ParamDef((D, E), ("embed", "experts"), scale=0.02),
+        "router": ParamDef((D, cfg.n_router_experts), ("embed", "experts"),
+                           scale=0.02),
         "w_gate": ParamDef((E, D, Fe), ("experts", "embed", "expert_ff")),
         "w_up": ParamDef((E, D, Fe), ("experts", "embed", "expert_ff")),
         "w_down": ParamDef((E, Fe, D), ("experts", "expert_ff", "embed")),
@@ -112,87 +159,104 @@ def moe_defs(cfg: ModelConfig) -> PyTree:
     return defs
 
 
-def _cap_shard(buf: jax.Array) -> jax.Array:
-    """Pin the capacity dim of the (E, C, D) expert buffer to 'model' —
-    with replicated expert weights the FFN becomes fully local (no TP psum
-    on the 2.5x-expanded buffer). §Perf hillclimb B."""
-    from jax.sharding import PartitionSpec as P
-
-    mesh = jax.sharding.get_abstract_mesh()
-    if "model" not in mesh.axis_names:
-        return buf
-    if buf.shape[-2] % mesh.shape["model"]:
-        return buf
-    return jax.lax.with_sharding_constraint(buf, P(None, "model", None))
-
-
-def _expert_ffn(params: PyTree, cfg: ModelConfig, xe: jax.Array) -> jax.Array:
-    """xe: (E, C, D) -> (E, C, D), batched over experts."""
-    if "w_gate" in params:
-        act = jax.nn.silu if cfg.mlp_type == "swiglu" else (
-            lambda v: jax.nn.gelu(v, approximate=True))
-        h = act(jnp.einsum("ecd,edf->ecf", xe, params["w_gate"])) * jnp.einsum(
-            "ecd,edf->ecf", xe, params["w_up"])
-    else:
-        h = jnp.square(jax.nn.relu(jnp.einsum("ecd,edf->ecf", xe, params["w_up"])))
-    return jnp.einsum("ecf,efd->ecd", h, params["w_down"])
-
-
 def moe_apply(
     params: PyTree, cfg: ModelConfig, x: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    """Top-k routed experts with capacity; returns (out, aux_loss).
+    """Top-k routed experts, dropless; returns (out, aux_loss).
 
-    x: (B, L, D).  Dispatch: tokens are scattered into per-expert capacity
-    buffers (E, C, D) (overflow drops), expert FFNs run batched, outputs are
-    gathered back weighted by the router probabilities.  Sharding the expert
-    dim over "model" yields expert parallelism (the scatter/gather lower to
-    all-to-all on the mesh); when E doesn't divide the mesh axis the ff dim
-    is sharded instead (tensor parallel experts) — see params.resolve_spec.
+    x: (B, L, D). The router scores all ``n_router_experts`` in float32 and
+    keeps the top k (softmax; the weights renormalised to sum 1 only with
+    ``norm_topk_prob``). This chip holds experts [expert_offset,
+    expert_offset + n_experts) and computes the routed part only for the
+    (token, expert) pairs whose expert it holds:
 
-    moe_dispatch='per_sequence' dispatches within each sequence independently
-    (capacity per sequence): scatter/gather indices never cross the batch dim,
-    so a batch-sharded mesh never all-gathers the token buffers — the fix for
-    the collective-bound MoE prefill found in EXPERIMENTS.md §Perf.
+        y = sum_{k: e_k held} w_k FFN_{e_k}(x) + SharedFFN(x).
+
+    The pairs are sorted by expert into a buffer of N * min(K, n_experts)
+    rows (N = B L), a static bound no token can exceed, so no pair is ever
+    dropped and a token's output does not depend on the rest of the batch;
+    the expert FFNs run as grouped matmuls over the held groups
+    (:func:`grouped_matmul`), and the results are weighted and added back
+    to their tokens. Nothing stands in for the experts held elsewhere.
     """
-    dispatch = getattr(cfg, "moe_dispatch", "global")
-    if dispatch == "per_sequence_smap":
-        # Partial-manual shard_map over the batch axes: dispatch gathers are
-        # device-local by construction (XLA SPMD replicates batched gathers
-        # otherwise — §Perf hillclimb B it3). Expert weights stay 'model'-auto.
-        from jax.sharding import PartitionSpec as P
-
-        mesh = jax.sharding.get_abstract_mesh()
-        wa = tuple(a for a in mesh.axis_names if a != "model")
-        n_shards = 1
-        for a in wa:
-            n_shards *= mesh.shape[a]
-        if wa and x.shape[0] % n_shards == 0 and n_shards > 1:
-            spec = P(wa[0] if len(wa) == 1 else wa, None, None)
-
-            def f(xb):
-                y, aux = jax.vmap(lambda s: _moe_tokens(params, cfg, s))(xb)
-                return y, jax.lax.pmean(aux.mean(), wa)
-
-            y, aux = jax.shard_map(f, mesh=mesh, in_specs=(spec,),
-                                      out_specs=(spec, P()),
-                                      axis_names=set(wa))(x)
-            if cfg.n_shared_experts:
-                y = y + _shared_expert(params, cfg, x)
-            return y, aux
-        dispatch = "per_sequence"  # fallback: no mesh / indivisible batch
-    if dispatch == "per_sequence":
-        y, aux = jax.vmap(lambda xb: _moe_tokens(params, cfg, xb))(x)
-        out = y
-        if cfg.n_shared_experts:
-            out = out + _shared_expert(params, cfg, x)
-        return out, aux.mean()
     B, L, D = x.shape
-    out, aux = _moe_tokens(params, cfg, x.reshape(B * L, D))
-    out = out.reshape(B, L, D)
+    xf = x.reshape(B * L, D)
+    with jax.named_scope("router"):
+        topw, topi, aux = _route(params["router"], cfg, xf, B)
+    with jax.named_scope("permute"):
+        order, group_sizes = _permute(cfg, topi)
+        tok = order // cfg.top_k
+        xs = xf[tok]
+    with jax.named_scope("experts"):
+        ys = _expert_ffn(params, cfg, xs, group_sizes)
+    with jax.named_scope("unpermute"):
+        valid = jnp.arange(order.shape[0]) < jnp.sum(group_sizes)
+        w = jnp.where(valid, topw.reshape(-1)[order], 0.0)
+        y = jnp.zeros((B * L, D), jnp.float32).at[tok].add(
+            ys.astype(jnp.float32) * w[:, None])
+        out = y.astype(x.dtype).reshape(B, L, D)
     if cfg.n_shared_experts:
-        out = out + _shared_expert(params, cfg, x)
+        with jax.named_scope("shared"):
+            out = out + _shared_expert(params, cfg, x)
     return out, aux
+
+
+def _route(router: jax.Array, cfg: ModelConfig, xf: jax.Array, n_seq: int):
+    """(top-k weights, top-k expert ids, balance loss) of (N, D) tokens
+    that are ``n_seq`` sequences of equal length.
+
+    ``moe_aux='seq'`` (DeepSeek-V2): per sequence f_e = count_e E / (L K)
+    and P_e = mean_t prob_e, loss coef * mean_seq sum_e f_e P_e.
+    ``'global'`` (Switch): coef * E * sum_e mean_t prob_e * count_e / (N K).
+    """
+    E, K = cfg.n_router_experts, cfg.top_k
+    N = xf.shape[0]
+    logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topw, topi = jax.lax.top_k(probs, K)
+    if cfg.norm_topk_prob:
+        topw = topw / jnp.clip(topw.sum(-1, keepdims=True), 1e-9)
+    counts = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.float32), axis=1)
+    if cfg.moe_aux == "seq":
+        L = N // n_seq
+        f = counts.reshape(n_seq, L, E).sum(1) * (E / (L * K))
+        p = probs.reshape(n_seq, L, E).mean(1)
+        aux = cfg.router_aux_coef * jnp.mean(jnp.sum(f * p, -1))
+    elif cfg.moe_aux == "global":
+        aux = cfg.router_aux_coef * E * jnp.sum(
+            probs.mean(0) * counts.sum(0) / (N * K))
+    else:
+        raise ValueError(cfg.moe_aux)
+    return topw, topi, aux
+
+
+def _permute(cfg: ModelConfig, topi: jax.Array):
+    """(order, group_sizes): ``order`` lists the flat (token, k) pair
+    indices of the held pairs sorted by expert, then as many others as the
+    static bound N * min(K, n_experts) leaves room for; ``group_sizes``
+    (n_experts,) counts the held pairs of each expert."""
+    N, K = topi.shape
+    E = cfg.n_experts
+    local = topi.reshape(-1) - cfg.expert_offset
+    key = jnp.where((local >= 0) & (local < E), local, E)
+    order = jnp.argsort(key, stable=True)[:N * min(K, E)]
+    group_sizes = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
+    return order, group_sizes
+
+
+def _expert_ffn(params: PyTree, cfg: ModelConfig, xs: jax.Array,
+                group_sizes: jax.Array) -> jax.Array:
+    """(P, D) rows sorted by expert -> (P, D) through each row's expert."""
+    if "w_gate" in params:
+        act = jax.nn.silu if cfg.mlp_type == "swiglu" else (
+            lambda v: jax.nn.gelu(v, approximate=True))
+        h = act(grouped_matmul(xs, params["w_gate"], group_sizes)) \
+            * grouped_matmul(xs, params["w_up"], group_sizes)
+    else:
+        h = jnp.square(jax.nn.relu(
+            grouped_matmul(xs, params["w_up"], group_sizes)))
+    return grouped_matmul(h, params["w_down"], group_sizes)
 
 
 def _shared_expert(params, cfg: ModelConfig, x):
@@ -201,45 +265,106 @@ def _shared_expert(params, cfg: ModelConfig, x):
     return h @ sh["w_down"]
 
 
-def _moe_tokens(params: PyTree, cfg: ModelConfig, xf: jax.Array):
-    """Routed-expert compute over a flat token matrix xf: (N, D)."""
-    N, D = xf.shape
-    E, K = cfg.n_experts, cfg.top_k
-    logits = (xf @ params["router"]).astype(jnp.float32)  # (N, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, K)                  # (N, K)
-    topw = topw / jnp.clip(topw.sum(-1, keepdims=True), 1e-9)
+# ---------------------------------------------------------------------------
+# Grouped matmul (the experts' FFN over rows sorted by expert)
+# ---------------------------------------------------------------------------
 
-    # load-balance aux loss (Switch-style)
-    me = probs.mean(0)                                    # mean prob per expert
-    ce = jnp.zeros((E,), jnp.float32).at[topi.reshape(-1)].add(1.0) / (N * K)
-    aux = E * jnp.sum(me * ce) * cfg.router_aux_coef
 
-    capacity = int(np.ceil(N * K / E * cfg.capacity_factor))
-    # position of each (token, k) within its expert queue
-    onehot = jax.nn.one_hot(topi, E, dtype=jnp.int32)     # (N, K, E)
-    flat_oh = onehot.reshape(N * K, E)
-    pos_in_e = (jnp.cumsum(flat_oh, axis=0) - flat_oh)    # (N*K, E)
-    pos = (pos_in_e * flat_oh).sum(-1).reshape(N, K)      # (N, K)
-    keep = pos < capacity
-    slot = jnp.where(keep, topi * capacity + pos, E * capacity)  # overflow bin
+def grouped_matmul(x: jax.Array, w: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """x (P, K) rows sorted by group, w (G, K, N), group_sizes (G,) int32
+    -> (P, N): each row times the weight of the group it falls in; rows
+    past sum(group_sizes) come out 0, in the forward and in x's gradient
+    (:func:`_zero_tail`).
 
-    # Scatter only token INDICES into the slot table (D-free, int32 — tiny),
-    # then fetch values with a gather: batched value-scatters force XLA SPMD
-    # to all-gather the (E·C, D) buffer over the batch axis; batched gathers
-    # partition cleanly (EXPERIMENTS.md §Perf hillclimb B).
-    inv = jnp.full((E * capacity + 1,), N, jnp.int32)
-    for k in range(K):
-        inv = inv.at[slot[:, k]].set(jnp.arange(N, dtype=jnp.int32))
-    xf_pad = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)], axis=0)
-    buf = xf_pad[inv[:-1]].reshape(E, capacity, D)
-    if cfg.moe_shard == "capacity":
-        buf = _cap_shard(buf)
-    out_e = _expert_ffn(params, cfg, buf)
-    out_flat = jnp.concatenate(
-        [out_e.reshape(E * capacity, D), jnp.zeros((1, D), xf.dtype)], axis=0
-    )
-    y = jnp.zeros((N, D), xf.dtype)
-    for k in range(K):
-        y = y + out_flat[slot[:, k]] * (topw[:, k] * keep[:, k].astype(jnp.float32))[:, None].astype(xf.dtype)
-    return y, aux
+    ``lax.ragged_dot``, whose TPU form takes no batch dimension: under a
+    ``vmap`` (the train step's over workers) the calls run per worker, in a
+    ``shard_map`` over the mesh's worker axes where the workers are spread
+    over them, else one after another (:func:`_per_worker`). The gradient
+    is ragged_dot's own, per worker the same way.
+    """
+    return _gmm(x, w, group_sizes)
+
+
+@jax.custom_vjp
+def _gmm(x, w, group_sizes):
+    return _ragged(x, w, group_sizes)
+
+
+def _gmm_fwd(x, w, group_sizes):
+    return _ragged(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _gmm_bwd(res, g):
+    x, w, group_sizes = res
+    dx, dw = _ragged_vjp(x, w, group_sizes, g)
+    return dx, dw, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _zero_tail(y, group_sizes):
+    """Rows of ``y`` past sum(group_sizes) set to 0: the TPU's ragged dot
+    leaves them unwritten, so they hold whatever the buffer held."""
+    rows = jnp.arange(y.shape[0])[:, None]
+    return jnp.where(rows < jnp.sum(group_sizes), y, jnp.zeros((), y.dtype))
+
+
+def _ragged_dot(x, w, group_sizes):
+    return _zero_tail(jax.lax.ragged_dot(x, w, group_sizes), group_sizes)
+
+
+def _ragged_dot_vjp(x, w, group_sizes, g):
+    _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, group_sizes),
+                     x, w)
+    dx, dw = vjp(g)
+    return _zero_tail(dx, group_sizes), dw
+
+
+@jax.custom_batching.custom_vmap
+def _ragged(x, w, group_sizes):
+    return _ragged_dot(x, w, group_sizes)
+
+
+@_ragged.def_vmap
+def _ragged_batched(axis_size, in_batched, x, w, group_sizes):
+    args = _batch_all(axis_size, in_batched, (x, w, group_sizes))
+    return _per_worker(_ragged_dot, args), True
+
+
+@jax.custom_batching.custom_vmap
+def _ragged_vjp(x, w, group_sizes, g):
+    return _ragged_dot_vjp(x, w, group_sizes, g)
+
+
+@_ragged_vjp.def_vmap
+def _ragged_vjp_batched(axis_size, in_batched, x, w, group_sizes, g):
+    args = _batch_all(axis_size, in_batched, (x, w, group_sizes, g))
+    return _per_worker(_ragged_dot_vjp, args), (True, True)
+
+
+def _batch_all(n: int, batched, args):
+    return tuple(a if b else jnp.broadcast_to(a, (n,) + a.shape)
+                 for a, b in zip(args, batched))
+
+
+def _per_worker(f, args):
+    """``f`` over the leading (worker) dim of ``args``: inside a shard_map
+    over the mesh's non-``model`` axes when their size is that dim (one
+    worker per device group), else in a loop."""
+    from jax.sharding import PartitionSpec as P
+
+    n = args[0].shape[0]
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = () if mesh.empty else tuple(
+        a for a in mesh.axis_names if a != "model")
+    if axes and math.prod(mesh.shape[a] for a in axes) == n:
+        spec = P(axes[0] if len(axes) == 1 else axes)
+        local = lambda *a: jax.tree.map(lambda y: y[None],
+                                        f(*(t[0] for t in a)))
+        out_spec = jax.tree.map(lambda _: spec,
+                                jax.eval_shape(f, *(t[0] for t in args)))
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
+                             out_specs=out_spec, axis_names=set(axes))(*args)
+    return jax.lax.map(lambda a: f(*a), args)

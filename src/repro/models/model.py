@@ -359,6 +359,42 @@ def forward(params, cfg: ModelConfig, tokens, *, q_base: int = 0,
     return h, new_caches, aux_total
 
 
+def routing_counts(params, cfg: ModelConfig, tokens) -> jax.Array:
+    """(expert layers, n_experts) int32: the (token, expert) pairs each
+    expert this chip holds is routed, per expert layer, for (B, L) tokens
+    (the forward without its loss; ``train(routing=...)`` emits them)."""
+    x = _embed(params, cfg, tokens)
+    B = tokens.shape[0]
+    counts = []
+    for seg, sp in zip(plan_segments(cfg), params["segments"]):
+        layers = [layer_params(sp, i) for i in range(seg.length)]
+        for bp in layers:
+            if not seg.moe:
+                x, _, _ = _block_apply(bp, cfg, seg.kind, False, x, 0, None,
+                                       None, None)
+                continue
+            h = L.rmsnorm_apply(bp["norm1"], x, cfg.norm_eps)
+            mixed, _ = attn_lib.mla_apply(bp["mix"], cfg, h) \
+                if cfg.attention_type == "mla" else attn_lib.gqa_apply(
+                    bp["mix"], cfg, h, causal=True,
+                    window=_self_window(cfg, seg.kind))
+            x = x + mixed
+            h = L.rmsnorm_apply(bp["norm2"], x, cfg.norm_eps)
+            _, topi, _ = L._route(bp["mlp"]["router"], cfg,
+                                  h.reshape(-1, cfg.d_model), B)
+            counts.append(L._permute(cfg, topi)[1])
+            x = x + L.moe_apply(bp["mlp"], cfg, h)[0]
+    return jnp.stack(counts) if counts else jnp.zeros((0, cfg.n_experts),
+                                                      jnp.int32)
+
+
+def layer_params(seg, i: int):
+    """Layer ``i`` of a segment: stacked leaves (scanned) or a list."""
+    if isinstance(seg, (list, tuple)):
+        return seg[i]
+    return jax.tree.map(lambda x: x[i], seg)
+
+
 def logits_from_hidden(params, cfg: ModelConfig, h: jax.Array) -> jax.Array:
     W = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.einsum("bld,dv->blv", h, W.astype(h.dtype),

@@ -81,8 +81,18 @@ def train(
     ckpt_every: int = 0,
     ckpt_sharded: bool = False,
     verbose: bool = True,
+    routing: Callable[[PyTree, PyTree], jax.Array] | None = None,
 ) -> tuple[TrainState, History]:
     """Run `steps` iterations; `batches` yields per-step batch pytrees.
+
+    ``routing(params, batch)`` gives one worker's (expert layers, held
+    experts) counts of routed (token, expert) pairs (e.g.
+    ``lambda p, b: model.routing_counts(p, cfg, b["tokens"])``);
+    with a recording sink, each log window emits worker 0's at its last
+    step: the counter ``moe.held_pairs`` (pairs routed to held experts,
+    summed over layers) and the gauge ``moe.load_max_over_mean`` (the
+    largest, over layers, of the busiest held expert's load over the
+    mean). Nothing runs with the null sink.
 
     ``mesh`` accepts a raw jax mesh or a :class:`~repro.launch.mesh.WorkerMesh`;
     ``param_specs`` (shardings.param_pspecs output) composes gossip with
@@ -127,6 +137,8 @@ def train(
     # train() bit-matches plain train().
     tel = telemetry.get()
     dispatch = None       # the open train.dispatch span of this log window
+    route = jax.jit(routing) if routing is not None else None
+    batch = None
 
     def flush() -> None:
         nonlocal t_win, dispatch
@@ -142,6 +154,14 @@ def train(
             tel.complete("train.window", tel.now() - dur, dur, steps=n)
             tel.counter("train.steps", n)
             tel.gauge("train.loss", hist.loss[-1])
+            if routing is not None:
+                c = np.asarray(route(*jax.tree.map(lambda a: a[0],
+                                                   (state.params, batch))),
+                               np.float64)
+                if c.size:
+                    tel.counter("moe.held_pairs", float(c.sum()))
+                    tel.gauge("moe.load_max_over_mean", float(np.max(
+                        c.max(1) / np.maximum(c.mean(1), 1e-9))))
         pending.clear()
         t_win = time.perf_counter()
 

@@ -8,12 +8,11 @@ Covers the serving contracts CI gates on:
     warmup);
   * PagePool allocation invariants (dump page, retire/reuse).
 
-MoE archs with capacity routing (deepseek) are deliberately NOT bit-match
-tested against unbatched decoding: expert capacity is
-``ceil(N*K/E * capacity_factor)`` over the TOKEN BATCH, so a bucket-padded
-admission prefill (N = bucket) legitimately routes differently from an
-exact-length unbatched prefill (N = prompt_len). Those archs get a
-serves-all + determinism test instead.
+MoE archs (deepseek) are not bit-match tested against unbatched decoding:
+the expert layer is dropless, but a bucket-padded admission prefill sorts a
+different set of (token, expert) pairs into its grouped matmuls than an
+exact-length unbatched prefill, so results may differ in rounding. Those
+archs get a serves-all + determinism test instead.
 """
 import numpy as np
 import pytest
@@ -171,8 +170,8 @@ def test_continuous_bit_matches_unbatched_generate():
 
 def test_continuous_bit_matches_unbatched_mla():
     """Paged MLA (absorbed compressed-KV attention) exactness — with the
-    MoE switched off (see module docstring for why capacity routing makes
-    batched-vs-unbatched bit-match unattainable)."""
+    MoE switched off (see module docstring for why the expert layer is not
+    bit-match tested batched against unbatched)."""
     cfg = get_config("deepseek-v2-lite-16b", reduced=True, n_experts=0,
                      n_shared_experts=0, top_k=0)
     params = M.init(KEY, cfg)
@@ -186,9 +185,9 @@ def test_continuous_bit_matches_unbatched_mla():
 
 @pytest.mark.slow
 def test_continuous_moe_serves_all_and_is_deterministic():
-    """Capacity-routed MoE: exactness vs unbatched is out of scope (batch-
-    composition-dependent routing), but serving must complete every request
-    and be run-to-run deterministic."""
+    """MoE: exactness vs unbatched is out of scope (see the module
+    docstring), but serving must complete every request and be run-to-run
+    deterministic."""
     cfg = get_config("deepseek-v2-lite-16b", reduced=True)
     params = M.init(KEY, cfg)
     reqs = _requests(cfg, 6, max_prompt=7, max_new=6)
