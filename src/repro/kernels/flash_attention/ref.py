@@ -9,7 +9,8 @@ import numpy as np
 def attention_reference(q, k, v, *, causal: bool = True,
                         window: int | None = None,
                         scale: float | None = None) -> jax.Array:
-    """q: (B, H, Lq, hd); k/v: (B, Hkv, Lkv, hd) with H % Hkv == 0."""
+    """q: (B, H, Lq, hd); k: (B, Hkv, Lkv, hd); v: (B, Hkv, Lkv, hd_v) with
+    H % Hkv == 0."""
     B, H, Lq, hd = q.shape
     Hkv, Lkv = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -27,4 +28,4 @@ def attention_reference(q, k, v, *, causal: bool = True,
     s = jnp.where(ok, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bksh->bkgqh", p, v.astype(jnp.float32))
-    return o.reshape(B, H, Lq, hd).astype(q.dtype)
+    return o.reshape(B, H, Lq, v.shape[-1]).astype(q.dtype)

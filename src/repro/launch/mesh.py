@@ -16,6 +16,7 @@ devices *before* any jax import (see dryrun.py).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -219,3 +220,87 @@ def worker_axes(mesh) -> tuple[str, ...]:
 
 def n_workers(mesh) -> int:
     return WorkerMesh.ensure(mesh).n_workers
+
+
+# ---------------------------------------------------------------------------
+# Ops the compiler does not partition, run per worker or per device
+# ---------------------------------------------------------------------------
+#
+# Some ops the compiler does not split over a mesh or a vmap: the TPU
+# compiler refuses a Pallas call whose operands are sharded, and
+# ``lax.ragged_dot``'s TPU form takes no batch dim. These helpers put such
+# an op inside a ``shard_map`` over the mesh in context, so that each
+# device runs its own share. Axes a ``shard_map`` around the call has
+# already taken (manual axes) are left alone.
+
+
+def _free_axes(mesh) -> list[str]:
+    return [] if mesh.empty else [a for a in mesh.axis_names
+                                  if a not in mesh.manual_axes]
+
+
+def _entry(axes):
+    return axes[0] if len(axes) == 1 else tuple(axes)
+
+
+def batch_all(n: int, batched, args):
+    """``args`` with a leading dim of ``n`` each: the unbatched ones (a
+    ``custom_vmap`` rule's ``in_batched`` False) broadcast to it."""
+    import jax.numpy as jnp
+
+    return tuple(a if b else jnp.broadcast_to(a, (n,) + a.shape)
+                 for a, b in zip(args, batched))
+
+
+def per_worker(f, args, fold: bool = False):
+    """``f`` over the leading (worker) dim of ``args``: inside a shard_map
+    over the mesh's worker axes when their size is that dim (one worker per
+    device group), else in a loop, or, with ``fold``, in one call of ``f``
+    with the workers folded into the next dim (for an ``f`` that treats
+    that dim row by row, and splits it over the devices itself, as
+    :func:`per_device` does)."""
+    from jax.sharding import PartitionSpec as P
+
+    n = args[0].shape[0]
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = [a for a in _free_axes(mesh) if a != MODEL_AXIS]
+    if axes and math.prod(mesh.shape[a] for a in axes) == n:
+        spec = P(_entry(axes))
+        local = lambda *a: jax.tree.map(lambda y: y[None],
+                                        f(*(t[0] for t in a)))
+        out_spec = jax.tree.map(lambda _: spec,
+                                jax.eval_shape(f, *(t[0] for t in args)))
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
+                             out_specs=out_spec, axis_names=set(axes))(*args)
+    if fold:
+        out = f(*(t.reshape((n * t.shape[1],) + t.shape[2:]) for t in args))
+        return jax.tree.map(lambda y: y.reshape((n, -1) + y.shape[1:]), out)
+    return jax.lax.map(lambda a: f(*a), args)
+
+
+def per_device(f, args, model_dim: int | None = None):
+    """``f`` on ``args`` inside a shard_map over the free axes of the mesh
+    in context: the leading (row) dim split over the worker axes, and dim
+    ``model_dim`` over the model axis, each where every arg's size there
+    divides; over an axis that splits neither, each device runs the whole.
+    ``f`` must treat both dims slice by slice and give results that carry
+    them in the same places. With no mesh, or no free axis, ``f`` runs as
+    is."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.get_abstract_mesh()
+    free = _free_axes(mesh)
+    if not free:
+        return f(*args)
+    rows = [a for a in free if a != MODEL_AXIS]
+    split = [None] * (1 if model_dim is None else model_dim + 1)
+    if rows and all(x.shape[0] % math.prod(mesh.shape[a] for a in rows) == 0
+                    for x in args):
+        split[0] = _entry(rows)
+    if (model_dim is not None and MODEL_AXIS in free
+            and all(x.shape[model_dim] % mesh.shape[MODEL_AXIS] == 0
+                    for x in args)):
+        split[model_dim] = MODEL_AXIS
+    spec = P(*split)
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec,) * len(args),
+                         out_specs=spec, axis_names=set(free))(*args)

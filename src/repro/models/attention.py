@@ -1,11 +1,12 @@
 """Attention variants: GQA/MQA/MHA, MLA (DeepSeek-V2), sliding-window/local.
 
-Long sequences use a blockwise online-softmax formulation (flash-attention
-algorithm in pure JAX): the quadratic score matrix is never materialized, so
-prefill_32k fits VMEM/HBM budgets.  The Pallas kernel in
-``repro.kernels.flash_attention`` implements the same algorithm with explicit
-BlockSpec tiling for TPU; this module is its lowering-friendly XLA twin and
-the numerical oracle.
+Causal self-attention over a whole number of kernel blocks runs, on TPU,
+in the Pallas flash kernel of ``repro.kernels.flash_attention``, forward
+and backward (training and prefill; :func:`takes_flash_kernel`). Everything
+else, and every run off the TPU, takes the XLA paths here: dense scores for
+short kv, and for long kv a blockwise online-softmax formulation (the flash
+algorithm in pure JAX) that never materializes the quadratic score matrix.
+The blockwise twin is also the kernel's numerical oracle.
 
 KV caches:
   * full cache (B, S_max, K, hd) with insertion position,
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.models.layers import (rmsnorm_apply, rmsnorm_defs, rope,
                                  rope_inv_freq, yarn_mscale)
 from repro.models.params import ParamDef
@@ -148,10 +150,25 @@ def blockwise_attention(q, k, v, q_base: int, *, causal=True, window=None,
     return out.transpose(0, 2, 1, 3)
 
 
+def takes_flash_kernel(Lq: int, Lkv: int, q_base, *, causal: bool,
+                       window: int | None, kv_valid) -> bool:
+    """Whether attention runs in the Pallas flash kernel: on TPU, causal
+    self-attention from position 0 with no window and no key mask, over a
+    whole number of the kernel's blocks."""
+    return (flash_ops.on_tpu() and causal and window is None
+            and kv_valid is None and Lq == Lkv
+            and isinstance(q_base, (int, np.integer)) and q_base == 0
+            and flash_ops.pick_block(Lq) is not None)
+
+
 def attention_any(q, k, v, q_base, *, causal=True, window=None, kv_valid=None,
                   scale=None, block_threshold=1024) -> jax.Array:
-    """Dense for short kv, blockwise for long kv."""
+    """The flash kernel where :func:`takes_flash_kernel` holds; else dense
+    for short kv, blockwise for long kv."""
     Lkv = k.shape[1]
+    if takes_flash_kernel(q.shape[1], Lkv, q_base, causal=causal,
+                          window=window, kv_valid=kv_valid):
+        return flash_ops.attention(q, k, v, causal=True, scale=scale)
     if Lkv <= block_threshold or kv_valid is not None:
         q_pos = q_base + jnp.arange(q.shape[1])
         kv_pos = jnp.arange(Lkv)

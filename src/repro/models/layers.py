@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import batch_all, per_worker
 from repro.models.params import ParamDef
 
 PyTree = Any
@@ -280,8 +281,9 @@ def grouped_matmul(x: jax.Array, w: jax.Array,
     ``lax.ragged_dot``, whose TPU form takes no batch dimension: under a
     ``vmap`` (the train step's over workers) the calls run per worker, in a
     ``shard_map`` over the mesh's worker axes where the workers are spread
-    over them, else one after another (:func:`_per_worker`). The gradient
-    is ragged_dot's own, per worker the same way.
+    over them, else one after another
+    (:func:`repro.launch.mesh.per_worker`). The gradient is ragged_dot's
+    own, per worker the same way.
     """
     return _gmm(x, w, group_sizes)
 
@@ -329,8 +331,8 @@ def _ragged(x, w, group_sizes):
 
 @_ragged.def_vmap
 def _ragged_batched(axis_size, in_batched, x, w, group_sizes):
-    args = _batch_all(axis_size, in_batched, (x, w, group_sizes))
-    return _per_worker(_ragged_dot, args), True
+    args = batch_all(axis_size, in_batched, (x, w, group_sizes))
+    return per_worker(_ragged_dot, args), True
 
 
 @jax.custom_batching.custom_vmap
@@ -340,31 +342,5 @@ def _ragged_vjp(x, w, group_sizes, g):
 
 @_ragged_vjp.def_vmap
 def _ragged_vjp_batched(axis_size, in_batched, x, w, group_sizes, g):
-    args = _batch_all(axis_size, in_batched, (x, w, group_sizes, g))
-    return _per_worker(_ragged_dot_vjp, args), (True, True)
-
-
-def _batch_all(n: int, batched, args):
-    return tuple(a if b else jnp.broadcast_to(a, (n,) + a.shape)
-                 for a, b in zip(args, batched))
-
-
-def _per_worker(f, args):
-    """``f`` over the leading (worker) dim of ``args``: inside a shard_map
-    over the mesh's non-``model`` axes when their size is that dim (one
-    worker per device group), else in a loop."""
-    from jax.sharding import PartitionSpec as P
-
-    n = args[0].shape[0]
-    mesh = jax.sharding.get_abstract_mesh()
-    axes = () if mesh.empty else tuple(
-        a for a in mesh.axis_names if a != "model")
-    if axes and math.prod(mesh.shape[a] for a in axes) == n:
-        spec = P(axes[0] if len(axes) == 1 else axes)
-        local = lambda *a: jax.tree.map(lambda y: y[None],
-                                        f(*(t[0] for t in a)))
-        out_spec = jax.tree.map(lambda _: spec,
-                                jax.eval_shape(f, *(t[0] for t in args)))
-        return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
-                             out_specs=out_spec, axis_names=set(axes))(*args)
-    return jax.lax.map(lambda a: f(*a), args)
+    args = batch_all(axis_size, in_batched, (x, w, group_sizes, g))
+    return per_worker(_ragged_dot_vjp, args), (True, True)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import rglru as rglru_lib
@@ -31,6 +32,12 @@ from repro.models import ssm as ssm_lib
 from repro.models.params import ParamDef, init_tree
 
 PyTree = Any
+# remat keeps only the flash kernel's output and logsumexp (where a layer
+# ran it): the backward then runs no second forward kernel
+_remat = functools.partial(
+    jax.checkpoint,
+    policy=jax.checkpoint_policies.save_only_these_names(
+        flash_ops.RESIDUAL_NAME))
 
 
 # ---------------------------------------------------------------------------
@@ -259,7 +266,7 @@ def encode(params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
         for bp in enc["layers"]:
             x, _ = body(x, bp)
     else:
-        fn = jax.checkpoint(body) if cfg.remat else body
+        fn = _remat(body) if cfg.remat else body
         x, _ = jax.lax.scan(fn, x, enc["layers"])
     return L.rmsnorm_apply(enc["out_norm"], x, cfg.norm_eps)
 
@@ -315,7 +322,7 @@ def forward(params, cfg: ModelConfig, tokens, *, q_base: int = 0,
             for li in range(seg.length):
                 fn = functools.partial(_block_apply, cfg=cfg, kind=seg.kind, moe=seg.moe)
                 if cfg.remat:
-                    fn = jax.checkpoint(
+                    fn = _remat(
                         lambda bp, x, c, k, _f=fn: _f(bp, x=x, q_base=q_base,
                                                       cache=c, memory=memory,
                                                       cross_kv=k, lengths=lengths,
@@ -352,7 +359,7 @@ def forward(params, cfg: ModelConfig, tokens, *, q_base: int = 0,
                 xs = xs + (cache_s,)
             if has_ckv:
                 xs = xs + (ckv_s,)
-            fn = jax.checkpoint(body) if cfg.remat else body
+            fn = _remat(body) if cfg.remat else body
             (x, aux_total), ncs = jax.lax.scan(fn, (x, aux_total), xs)
             new_caches.append(ncs)
     h = L.rmsnorm_apply(params["out_norm"], x, cfg.norm_eps)

@@ -1,4 +1,5 @@
-"""Compile the main path's kernels and the sharded gossip bus for a TPU v5e.
+"""Compile the main path's kernels, the sharded gossip bus and the train
+steps with the flash-attention kernel for a TPU v5e.
 
 Nothing runs: the TPU compiler installed with JAX compiles for a described
 ``v5e:2x2`` topology, which refuses what the chip would refuse (unaligned
@@ -18,12 +19,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from repro.configs import get_config
 from repro.core import bus
 from repro.core import topology as T
+from repro.core.decentralized import init_state, make_train_step
 from repro.core.gossip import GossipSpec
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.gossip_mix.kernel import gossip_mix_2d
 from repro.kernels.quant_pack.kernel import quantize_pack_2d
 from repro.launch.hlo_cost import analyze_hlo
+from repro.launch.mesh import WorkerMesh
 from repro.models import model as M
 from repro.models.params import abstract_tree
+from repro.optim import momentum_sgd
 
 WORKERS = 4
 
@@ -140,3 +145,135 @@ def test_sharded_ring_bus_compiles_on_2x2(topo, with_specs):
     assert "tpu_custom_call" in hlo
     assert analyze_hlo(hlo).coll_counts["collective-permute"] == \
         bus.bulk_collectives_per_step(spec)
+
+
+# ---------------------------------------------------------------------------
+# The train steps with the Pallas flash-attention kernel
+#
+# Off the chip ``jax.default_backend()`` is ``cpu``, so each test makes the
+# kernel's backend probe answer TPU: ``attention_any`` then takes the kernel
+# and the kernel is compiled rather than interpreted. On the 2x2 mesh, one
+# worker per chip, the kernel runs per chip inside a ``shard_map`` and the
+# compiler puts in no all-gather. On one chip the workers fold into the
+# kernel's batch grid, and no attention kernel's result looks like another
+# kernel's to the benchmark's readers: no 2-D ``[rows,128]`` (the gossip mix)
+# and none of the grouped matmuls' shapes.
+# ---------------------------------------------------------------------------
+
+W = WORKERS
+_RESULT = re.compile(r"= \(?([a-z0-9]+)\[([\d,]*)\]")
+
+
+@pytest.fixture
+def tpu_probe(monkeypatch):
+    monkeypatch.setattr(flash_ops, "on_tpu", lambda: True)
+
+
+def _flash_calls(text):
+    """The flash kernels' custom-call lines of a compiled module."""
+    return [ln.strip() for ln in text.splitlines()
+            if "custom-call(" in ln and "tpu_custom_call" in ln
+            and "%flash_attention" in ln]
+
+
+def _compile_step(cfg, seq, sharding, mesh=None):
+    opt = momentum_sgd(1e-2, 0.9)
+    if mesh is None:
+        spec, wm = GossipSpec(topology=T.make("ring", W), backend="fused"), None
+    else:
+        wm = WorkerMesh.from_mesh(mesh)
+        spec = GossipSpec.for_mesh(T.make("ring", W), wm, backend="fused")
+    step = make_train_step(lambda p, b: M.loss_fn(p, cfg, b), opt,
+                           gossip=spec, mesh=wm, compute_stats=mesh is None)
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: jnp.broadcast_to(x, (W,) + x.shape),
+        M.init(jax.random.PRNGKey(0), cfg)))
+    state = jax.eval_shape(lambda p: init_state(p, opt), params)
+    scalar = sharding if mesh is None else NamedSharding(mesh, P())
+    state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sharding if s.ndim else scalar), state)
+    batch = {"tokens": jax.ShapeDtypeStruct((W, 1, seq + 1), jnp.int32,
+                                            sharding=sharding)}
+    return jax.jit(step, donate_argnums=(0,)).lower(
+        state, batch).compile().as_text()
+
+
+def _flash_mesh(topo):
+    return jax.make_mesh((W,), ("data",), devices=topo.devices[:W],
+                         axis_types=(jax.sharding.AxisType.Auto,))
+
+
+@pytest.mark.parametrize("arch", ["granite", "dsv2lite"])
+def test_mesh_step_runs_the_kernel_per_chip_without_all_gather(
+        topo, tpu_probe, arch):
+    """granite-3-2b's layer at published widths (GQA 32/8 heads of 64),
+    and DeepSeek-V2-Lite's latent attention (qk 192, v 128) on a narrow
+    residual: forward, dQ and dK/dV kernels, each per chip."""
+    if arch == "granite":
+        cfg = get_config("granite-3-2b", n_layers=1)
+        seq, heads, kv_heads, d_qk, d_v = 1024, 32, 8, 64, 64
+    else:
+        cfg = get_config("deepseek-v2-lite-16b", n_layers=2, d_model=256,
+                         d_ff=512, vocab_size=1024, n_experts=8,
+                         router_experts=64, d_ff_expert=256, remat=True,
+                         scan_layers=True)
+        seq, heads, kv_heads, d_qk, d_v = 512, 16, 16, 192, 128
+    mesh = _flash_mesh(topo)
+    with jax.set_mesh(mesh):
+        text = _compile_step(cfg, seq, NamedSharding(mesh, P("data")), mesh)
+    assert analyze_hlo(text).coll_counts["all-gather"] == 0
+    results = {_RESULT.search(ln).group(2) for ln in _flash_calls(text)}
+    # one row per chip: forward o, dQ, and dK
+    assert {f"1,{heads},{seq},{d_v}", f"1,{heads},{seq},{d_qk}",
+            f"1,{kv_heads},{seq},{d_qk}"} <= results, results
+
+
+def test_one_chip_step_folds_workers_into_the_kernel_grid(topo, tpu_probe):
+    """The vmapped one-chip step: 4 workers' rows in each kernel call, and
+    no attention result a benchmark reader would take for another kernel."""
+    cfg = get_config("granite-3-2b", n_layers=1)
+    text = _compile_step(cfg, 2048, SingleDeviceSharding(topo.devices[0]))
+    calls = _flash_calls(text)
+    shapes = [tuple(int(d) for d in _RESULT.search(ln).group(2).split(","))
+              for ln in calls]
+    # the forward once (remat keeps its output), dQ and dK/dV
+    assert sorted(ln.split(" = ")[0].split(".")[0] for ln in calls) == [
+        "%flash_attention_dkv", "%flash_attention_dq",
+        "%flash_attention_fwd"], calls
+    assert all(len(s) == 4 and s[0] == W for s in shapes), shapes
+    assert not [s for s in shapes if len(s) == 2 and s[1] == 128]
+    # dsv2lite-ring4-4chip's grouped matmuls: 4 x 4096 tokens x top-6
+    # pairs through d_ff_expert 1408 and d_model 2048, 8 experts held
+    gmm = {(98304, 1408), (98304, 2048), (8, 2048, 1408), (8, 1408, 2048)}
+    assert not gmm & set(shapes)
+    # every custom call's tuple elements too: no [rows,128] anywhere
+    for ln in calls:
+        head = ln.split("custom-call(")[0]
+        assert not re.search(r"\[\d+,128\]", head), head
+
+
+def test_allreduce_step_runs_the_kernel_per_chip_without_all_gather(
+        topo, tpu_probe):
+    """The all-reduce baseline on the 2x2 mesh: no vmap, one replicated
+    copy of granite-3-2b's layer, the batch's rows over the chips. Each
+    chip's forward, dQ and dK/dV kernels take its own row, and the compiler
+    gathers nothing to them."""
+    cfg = get_config("granite-3-2b", n_layers=1)
+    seq = 1024
+    mesh = _flash_mesh(topo)
+    opt = momentum_sgd(1e-2, 0.9)
+    step = make_train_step(lambda p, b: M.loss_fn(p, cfg, b), opt,
+                           mode="allreduce", mesh=WorkerMesh.from_mesh(mesh))
+    params = jax.eval_shape(lambda: M.init(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda p: init_state(p, opt), params)
+    replicated = NamedSharding(mesh, P())
+    state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=replicated), state)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (W, seq + 1), jnp.int32, sharding=NamedSharding(mesh, P("data")))}
+    with jax.set_mesh(mesh):
+        text = jax.jit(step, donate_argnums=(0,)).lower(
+            state, batch).compile().as_text()
+    assert analyze_hlo(text).coll_counts["all-gather"] == 0
+    results = {_RESULT.search(ln).group(2) for ln in _flash_calls(text)}
+    assert {f"1,32,{seq},64", f"1,8,{seq},64"} <= results, results
